@@ -127,10 +127,10 @@ CONFIGS = [SMOKE] + [
 ]
 
 
-def _run_one(cfg: dict, which: str) -> dict:
-    """Run one config on one engine in-process; returns the measurement."""
-    if which == "solver":
-        return _run_solver(cfg)
+def workload(cfg: dict):
+    """``(pipelines, arrivals, duration_s)`` of a fleet config: the
+    asset_damage/content_moderation mix at ``utilization`` of the DSCS
+    fleet's median service rate, for ``n_requests_target`` requests."""
     from repro.core.arrivals import make_arrivals
     from repro.core.latency import LatencyModel
     from repro.core.function import standard_pipeline
@@ -142,8 +142,15 @@ def _run_one(cfg: dict, which: str) -> dict:
     svc = sum(lm.e2e(PLATFORMS["DSCS-Serverless"], p.workload, q=0.5)
               for p in pipes) / len(pipes)
     rate = cfg["utilization"] * cfg["n_dscs"] / svc
-    duration = cfg["n_requests_target"] / rate
-    arrivals = make_arrivals(cfg["arrival"], rate)
+    return (pipes, make_arrivals(cfg["arrival"], rate),
+            cfg["n_requests_target"] / rate)
+
+
+def _run_one(cfg: dict, which: str) -> dict:
+    """Run one config on one engine in-process; returns the measurement."""
+    if which == "solver":
+        return _run_solver(cfg)
+    pipes, arrivals, duration = workload(cfg)
 
     if which == "engine":
         from repro.core.engine import ClusterEngine
@@ -286,20 +293,9 @@ def _smoke_shards(args) -> int:
     asserts shard-count independence — the partitioned path must emit
     byte-identical finish times for 2 and 4 shards.
     """
-    from repro.core.arrivals import make_arrivals
     from repro.core.engine import ClusterEngine
-    from repro.core.function import standard_pipeline
-    from repro.core.latency import LatencyModel
-    from repro.core.platforms import PLATFORMS
 
-    pipes = [standard_pipeline(n)
-             for n in ("asset_damage", "content_moderation")]
-    lm = LatencyModel()
-    svc = sum(lm.e2e(PLATFORMS["DSCS-Serverless"], p.workload, q=0.5)
-              for p in pipes) / len(pipes)
-    rate = SMOKE["utilization"] * SMOKE["n_dscs"] / svc
-    duration = SMOKE["n_requests_target"] / rate
-
+    pipes, arrivals, duration = workload(SMOKE)
     rps, finishes = {}, {}
     for k in (1, 2, 4):
         best, trace = 0.0, None
@@ -309,8 +305,7 @@ def _smoke_shards(args) -> int:
                                 hedge_budget_s=SMOKE["hedge_budget_s"],
                                 seed=0)
             t0 = time.perf_counter()
-            trace = eng.run_sharded(pipes,
-                                    arrivals=make_arrivals("poisson", rate),
+            trace = eng.run_sharded(pipes, arrivals=arrivals,
                                     duration_s=duration, n_shards=k,
                                     processes=1)
             best = max(best, trace.n / (time.perf_counter() - t0))

@@ -43,10 +43,6 @@ SEED = 0                                # benchmarks.run --seed rebinds; every
                                         # simulation figure draws from it so
                                         # montecarlo can fan one config across
                                         # many seeds
-BACKEND = "segmented"                   # benchmarks.run --backend rebinds:
-                                        # Lindley solver for any sharded
-                                        # figure sweep (repro.core.lindley;
-                                        # all backends bit-identical)
 
 
 def _ratio(num: float, den: float) -> float:

@@ -49,16 +49,21 @@ def ci95(values: Sequence[float]) -> Tuple[float, Optional[float]]:
     return mean, 1.96 * math.sqrt(var / n)
 
 
-def _run_one_seed(seed: int, only: str, smoke: bool,
-                  backend: str = "") -> List[dict]:
+def seed_command(seed: int, only: str, smoke: bool) -> List[str]:
+    """The ``benchmarks.run`` command line for one seed.  It always
+    filters to figures (every figure is named ``fig*``): the kernel rows
+    that an unfiltered run adds are seed-independent timings that need
+    JAX and the accelerator, which concurrent seed processes would
+    contend for."""
     cmd = [sys.executable, "-m", "benchmarks.run", "--json",
-           "--seed", str(seed)]
-    if only:
-        cmd += ["--only", only]
+           "--seed", str(seed), "--only", only or "fig"]
     if smoke:
         cmd += ["--smoke"]
-    if backend:
-        cmd += ["--backend", backend]
+    return cmd
+
+
+def _run_one_seed(seed: int, only: str, smoke: bool) -> List[dict]:
+    cmd = seed_command(seed, only, smoke)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(REPO, "src"), REPO,
@@ -113,10 +118,6 @@ def main(argv=None) -> None:
                     help="CI-sized fast path for every figure")
     ap.add_argument("--jobs", type=int, default=1,
                     help="seed subprocesses to run concurrently")
-    ap.add_argument("--backend", default="",
-                    choices=("", "segmented", "pallas", "dense"),
-                    help="forwarded to benchmarks.run --backend (Lindley "
-                         "solver for sharded sweeps; default unchanged)")
     ap.add_argument("--json", default="", metavar="PATH",
                     help="write the figures/v2 envelope here instead of "
                          "stdout CSV")
@@ -129,11 +130,9 @@ def main(argv=None) -> None:
         from concurrent.futures import ThreadPoolExecutor
         with ThreadPoolExecutor(max_workers=args.jobs) as pool:
             per_seed = list(pool.map(
-                lambda s: _run_one_seed(s, args.only, args.smoke,
-                                        args.backend), seeds))
+                lambda s: _run_one_seed(s, args.only, args.smoke), seeds))
     else:
-        per_seed = [_run_one_seed(s, args.only, args.smoke, args.backend)
-                    for s in seeds]
+        per_seed = [_run_one_seed(s, args.only, args.smoke) for s in seeds]
 
     rows = aggregate(per_seed)
     envelope = {"schema": "figures/v2", "seeds": args.seeds,

@@ -25,6 +25,7 @@ def _kernel_micro():
     against the ref oracle."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from repro.kernels import ops, ref
 
     rows = []
@@ -38,10 +39,9 @@ def _kernel_micro():
         out = fn()
         jax.block_until_ready(out)
         us = (time.perf_counter() - t0) * 1e6
-        err = float(jnp.max(jnp.abs(
-            (out[0] if isinstance(out, (tuple, list)) else out).astype(jnp.float32)
-            - (reference[0] if isinstance(reference, (tuple, list)) else reference)
-            .astype(jnp.float32))))
+        first = lambda v: v[0] if isinstance(v, (tuple, list)) else v
+        err = float(np.max(np.abs(np.asarray(first(out), np.float64)
+                                  - np.asarray(first(reference), np.float64))))
         rows.append((f"kernel/{name}", us, f"max_err={err:.2e}"))
 
     timed("systolic_matmul_256", lambda: ops.matmul(x, w),
@@ -66,14 +66,10 @@ def _kernel_micro():
     Bm = jax.random.normal(key, (1, 128, 1, 8)) * 0.3
     timed("ssd_scan", lambda: ops.ssd(xs, dt, A, Bm, Bm, chunk=32),
           ref.ssd_ref(xs, dt, A, Bm, Bm, chunk=32))
-    import numpy as np
-    from jax.experimental import enable_x64
     rng = np.random.default_rng(3)
     tq = np.sort(rng.uniform(0.0, 50.0, size=(64, 128)), axis=1)
     sq = rng.uniform(1e-3, 2.0, size=(64, 128))
-    with enable_x64():
-        lref = ref.lindley_ref(jnp.asarray(tq), jnp.asarray(sq))
-    timed("lindley_scan", lambda: ops.lindley(tq, sq), lref)
+    timed("lindley_scan", lambda: ops.lindley(tq, sq), ref.lindley_ref(tq, sq))
     return rows
 
 
@@ -111,16 +107,10 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0,
                     help="simulation seed for every figure (montecarlo "
                          "fans one config across many seeds)")
-    ap.add_argument("--backend", default="segmented",
-                    choices=("segmented", "pallas", "dense"),
-                    help="Lindley solver backend for sharded figure "
-                         "sweeps (repro.core.lindley; all backends are "
-                         "bit-identical, default unchanged)")
     args = ap.parse_args(argv)
     if args.smoke:
         figures_mod.SMOKE = True
     figures_mod.SEED = args.seed
-    figures_mod.BACKEND = args.backend
     figures = [f for f in ALL_FIGURES
                if args.only.lower() in f.__name__.lower()]
     if args.list_figs:
@@ -155,6 +145,8 @@ def main(argv=None) -> None:
             emit(name, val, derived)
         emit(f"{fig.__name__}/wall", dt, "us")
     if not args.only:
+        from repro.jax_cache import use_compile_cache
+        use_compile_cache()
         for name, us, derived in _kernel_micro():
             emit(name, us, derived)
         for name, val, derived in _roofline_summary():

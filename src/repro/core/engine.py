@@ -2742,11 +2742,15 @@ class ClusterEngine:
         tune the bounded cross-shard mailbox.  Multi-tenant runs are not
         supported sharded — use ``n_shards=1``.  ``backend`` selects the
         fast path's Lindley solver (``segmented``/``pallas``/``dense``,
-        see :mod:`repro.core.lindley` — all bit-identical; ``n_shards=1``
-        and the shard-isolated fallback run the classic event loop and
-        ignore it).
+        see :mod:`repro.core.lindley`); ``n_shards=1`` and the
+        shard-isolated fallback run the classic event loop on the host,
+        so they take only the default.
         """
         if n_shards == 1:
+            if backend != "segmented":
+                raise ValueError(
+                    f"backend={backend!r} cannot run with n_shards=1: "
+                    "that is the classic event loop on the host")
             return self.run_soa(pipelines, arrivals=arrivals,
                                 duration_s=duration_s, times=times,
                                 timeout_s=timeout_s, overload=overload)

@@ -31,6 +31,7 @@ class ExecutionReport:
     energy_breakdown: Dict[str, float]
     platform: str
     accelerated: bool
+    output: Any              # f2's raw output (logits), before f3
 
 
 def _preprocess_vector_engine(img: jax.Array, use_kernel: bool) -> jax.Array:
@@ -58,11 +59,31 @@ _MODEL_BUILDERS: Dict[str, Tuple[Callable, Callable, dict]] = {
 }
 
 
+def _split_arrays(tree) -> Tuple[list, Callable]:
+    """The array leaves of ``tree`` and a function that rebuilds it from
+    them: non-array leaves (strides, head counts) stay Python constants
+    when the model is jitted."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    is_arr = [isinstance(v, jax.Array) for v in leaves]
+
+    def rebuild(arrays):
+        it = iter(arrays)
+        return jax.tree_util.tree_unflatten(
+            treedef, [next(it) if a else v for v, a in zip(leaves, is_arr)])
+    return [v for v, a in zip(leaves, is_arr) if a], rebuild
+
+
 class DSCSExecutor:
-    """Executes one Table I pipeline end-to-end in a chosen deployment."""
+    """Executes one Table I pipeline end-to-end in a chosen deployment.
+
+    Vision pipelines run f1 and f2 as one jitted program.  ``width``
+    overrides the model's channel multiplier (``1.0`` is the published
+    width; the default is the reduced width in ``_MODEL_BUILDERS``).
+    """
 
     def __init__(self, workload_name: str, *, platform: str = "DSCS-Serverless",
-                 image_size: int = 64, seed: int = 0):
+                 image_size: int = 64, seed: int = 0,
+                 width: Optional[float] = None):
         self.pipeline = standard_pipeline(
             workload_name, accelerate=(platform == "DSCS-Serverless"))
         self.platform = PLATFORMS[platform]
@@ -71,8 +92,17 @@ class DSCSExecutor:
         key = jax.random.PRNGKey(seed)
         if workload_name in _MODEL_BUILDERS:
             init, apply, kw = _MODEL_BUILDERS[workload_name]
+            if width is not None:
+                kw = {**kw, "width": width}
             self.params = init(key, **kw)
-            self._apply = apply
+            self._arrays, rebuild = _split_arrays(self.params)
+            accel = self.platform.kind == "dsa"
+
+            def infer(arrays, request):
+                x = (_preprocess_vector_engine(request, accel)
+                     if request.dtype == jnp.uint8 else request)
+                return apply(rebuild(arrays), x, use_kernel=accel)
+            self._infer = jax.jit(infer)
         elif workload_name == "credit_risk":
             self.params = jax.random.normal(key, (200, 1)) * 0.1
             self._apply = lambda p, x, use_kernel=False: jax.nn.sigmoid(x @ p)
@@ -94,19 +124,20 @@ class DSCSExecutor:
         s = self.image_size
         return jax.random.randint(key, (1, s, s, 3), 0, 256).astype(jnp.uint8)
 
+    def lower(self, request: jax.Array):
+        """The lowered f1+f2 program a vision pipeline runs for
+        ``request`` (``.as_text()`` shows whether kernels compiled)."""
+        return self._infer.lower(self._arrays, request)
+
     def __call__(self, request: jax.Array) -> ExecutionReport:
         accel = self.platform.kind == "dsa"
         name = self.pipeline.name
-        # f1 — pre-process
-        if request.dtype == jnp.uint8:
-            x = _preprocess_vector_engine(request, use_kernel=accel)
-        else:
-            x = request
-        # f2 — inference (systolic kernels on the DSA path)
+        # f1 — pre-process (vector engine), f2 — inference (systolic
+        # kernels on the DSA path)
         if name in _MODEL_BUILDERS:
-            y = self._apply(self.params, x, use_kernel=accel)
+            y = self._infer(self._arrays, request)
         else:
-            y = self._apply(self.params, x)
+            y = self._apply(self.params, request)
         # f3 — post/notify
         if y.ndim >= 2 and y.shape[-1] > 1:
             result = jnp.argmax(y, axis=-1)
@@ -116,4 +147,5 @@ class DSCSExecutor:
         en = pipeline_energy_j(self.lm, self.platform, self.pipeline.workload)
         return ExecutionReport(result=result, latency_breakdown=lat,
                                energy_breakdown=en,
-                               platform=self.platform.name, accelerated=accel)
+                               platform=self.platform.name, accelerated=accel,
+                               output=y)

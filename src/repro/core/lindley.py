@@ -33,19 +33,22 @@ Two backends evaluate the identity over the contiguous flat layout:
     would have broken the bit-identity gate.
 
 ``pallas``
-    The same bucketed recurrence as a grid-blocked Pallas TPU kernel
-    (:mod:`repro.kernels.lindley`): rows ride the lane dimension, the
-    depth axis is scanned sequentially with a grid-carried fp64 VMEM
-    ``(cumsum, running-max)`` state — float64 via jax's x64 mode,
-    ``interpret=True`` off-TPU like every other kernel in the repo.
-    Because the kernel performs the same fp64 operations in the same
-    order, its output is bit-identical to the numpy backend (pinned in
-    ``tests/test_kernels.py``).
+    The same buckets solved on the device by a grid-blocked Pallas TPU
+    kernel (:mod:`repro.kernels.lindley`): rows ride the lane dimension
+    and the depth axis is scanned sequentially.  TPUs have no float64,
+    so the kernel carries the Lindley *waiting time* in float32 while
+    the host keeps arrivals and starts in float64 (``interpret=True``
+    off-TPU like every other kernel in the repo).  Its starts are not
+    bit-identical to the numpy backends: they lie within the absolute
+    bound of :func:`repro.kernels.lindley.error_bound`, which grows
+    with a queue's backlog inside one busy period, not with simulated
+    time (pinned in ``tests/test_kernels.py``).
 
 ``dense``
     The legacy zero-padded ``(n_servers, longest_queue)`` layout, kept
     as the perf baseline ``benchmarks/bench_engine.py`` measures the
-    skew speedup against.
+    skew speedup against.  ``segmented`` and ``dense`` are
+    byte-identical to each other.
 
 Scratch buffers are pooled per process (:data:`_POOL`) and reused across
 buckets, shards, and the accel/non-accel solve phases, so a long run
@@ -57,8 +60,8 @@ from typing import Dict, List
 
 import numpy as np
 
-__all__ = ["BACKENDS", "queue_depth_max", "segment_fenceposts",
-           "solve_segments"]
+__all__ = ["BACKENDS", "fcfs_queues", "queue_depth_max",
+           "segment_error_bound", "segment_fenceposts", "solve_segments"]
 
 BACKENDS = ("segmented", "pallas", "dense")
 
@@ -82,11 +85,10 @@ def segment_fenceposts(keys: np.ndarray, lo: int, hi: int) -> np.ndarray:
     return np.searchsorted(keys, np.arange(lo, hi + 1))
 
 
-def _solve_dense(seg: np.ndarray, t: np.ndarray, s: np.ndarray,
-                 start: np.ndarray) -> None:
-    """Legacy padded-dense evaluation: one ``(n_servers, longest)``
-    zero-padded block (pads sit after each row's data, so the row-wise
-    prefix scans never see them)."""
+def _dense_layout(seg: np.ndarray, t: np.ndarray, s: np.ndarray):
+    """One ``(n_servers, longest)`` zero-padded block per column (pads
+    sit after each row's data, so row-wise prefix scans never see them)
+    plus the ``(rows, pos)`` gather indices back to the flat layout."""
     lens = np.diff(seg)
     nserv = lens.size
     rows = np.repeat(np.arange(nserv), lens)
@@ -96,6 +98,13 @@ def _solve_dense(seg: np.ndarray, t: np.ndarray, s: np.ndarray,
     S = np.zeros(shape)
     T[rows, pos] = t
     S[rows, pos] = s
+    return T, S, rows, pos
+
+
+def _solve_dense(seg: np.ndarray, t: np.ndarray, s: np.ndarray,
+                 start: np.ndarray) -> None:
+    """Legacy padded-dense evaluation over :func:`_dense_layout`."""
+    T, S, rows, pos = _dense_layout(seg, t, s)
     C = np.cumsum(S, axis=1)
     prev = C - S
     M = np.maximum.accumulate(T - prev, axis=1)
@@ -170,8 +179,9 @@ def solve_segments(seg: np.ndarray, t: np.ndarray, s: np.ndarray,
     """Fill ``start``/``fin`` for every segment's FCFS queue.
 
     ``seg`` are :func:`segment_fenceposts`; ``t`` (sorted per segment)
-    and ``s`` are the flat arrival/service columns.  All three backends
-    produce bit-identical results (see the module docstring).
+    and ``s`` are the flat arrival/service columns.  ``segmented`` and
+    ``dense`` produce byte-identical results; ``pallas`` agrees with
+    them within its float32 error bound (see the module docstring).
     """
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, "
@@ -183,6 +193,42 @@ def solve_segments(seg: np.ndarray, t: np.ndarray, s: np.ndarray,
     else:
         _solve_segmented(seg, t, s, start, pallas=(backend == "pallas"))
     np.add(start, s, out=fin)
+
+
+def segment_error_bound(seg: np.ndarray, t: np.ndarray,
+                        s: np.ndarray) -> np.ndarray:
+    """Per-row bound on how far the ``pallas`` backend's ``start`` (and
+    ``fin``) may lie from the float64 backends' for the flat layout of
+    :func:`solve_segments` — :func:`repro.kernels.lindley.error_bound`
+    over :func:`_dense_layout`."""
+    from repro.kernels.lindley import error_bound
+    if not t.size:
+        return np.zeros(0)
+    T, S, rows, pos = _dense_layout(seg, t, s)
+    return error_bound(T, S)[rows, pos]
+
+
+def fcfs_queues(key: np.ndarray, arrival: np.ndarray, finish: np.ndarray,
+                n_servers: int):
+    """Rebuild the FCFS queues behind per-request columns of one server
+    class: ``key`` (server id), ``arrival`` (when the copy joined) and
+    ``finish`` (NaN where the request sent no copy).  Queues are ordered
+    by ``(arrival, request id)``, as the partitioned path solves them;
+    each copy started at ``max(arrival, previous finish)``.
+
+    Returns ``(rids, seg, t, s, start)`` in queue order, ``seg`` being
+    :func:`segment_fenceposts` — the inputs :func:`solve_segments` saw.
+    """
+    rid = np.flatnonzero(~np.isnan(finish))
+    rids = rid[np.lexsort((rid, arrival[rid], key[rid]))]
+    seg = segment_fenceposts(key[rids], 0, n_servers)
+    t = arrival[rids]
+    fin = finish[rids]
+    prev = np.empty_like(fin)
+    prev[1:] = fin[:-1]
+    prev[seg[:-1][np.diff(seg) > 0]] = -np.inf
+    start = np.maximum(t, prev)
+    return rids, seg, t, fin - start, start
 
 
 def queue_depth_max(seg: np.ndarray, start: np.ndarray,

@@ -57,7 +57,6 @@ from __future__ import annotations
 import math
 import multiprocessing as mp
 import os
-import warnings
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -338,7 +337,7 @@ def _grouped_fcfs(keys: np.ndarray, lo: int, hi: int, t: np.ndarray,
     (server ids in ``[lo, hi)``): `_fcfs_segment` batched over all
     servers at once through :mod:`repro.core.lindley` (length-bucketed
     segmented scan by default; ``backend`` selects the Pallas kernel or
-    the legacy padded-dense layout — all bit-identical).  Fills
+    the legacy padded-dense layout).  Fills
     ``start``/``fin`` in place and returns per-server (busy_s,
     queue-area, max-depth) lists."""
     nserv = hi - lo
@@ -758,11 +757,15 @@ def run_partitioned(engine, pipelines: Optional[Sequence[Pipeline]], *,
     :meth:`ClusterEngine.run_sharded`.
 
     ``backend`` picks the Lindley solver on the partitioned fast path
-    (:data:`repro.core.lindley.BACKENDS`: ``segmented``/``pallas``/
-    ``dense`` — all bit-identical); the shard-isolated fallback runs the
-    classic event loop and ignores it — a non-default ``backend`` on a
-    fallback run raises a ``UserWarning`` so the Pallas/segmented knob
-    never silently does nothing.
+    (:data:`repro.core.lindley.BACKENDS`: ``segmented`` and ``dense``
+    are byte-identical, ``pallas`` solves on the device within the
+    float32 bound of :func:`repro.kernels.lindley.error_bound`).  The
+    shard-isolated fallback runs the classic event loop on the host, so
+    any other ``backend`` there raises (for ``pallas``, instead of
+    quietly running without the device).  ``pallas`` also runs the
+    shards in this one process (``processes`` defaults to 1, and more
+    raises): forked workers cannot use the accelerator their parent
+    holds.
 
     ``overload`` (or the engine-level config) routes the run through the
     shard-isolated fallback; each shard runs its own control loop
@@ -780,7 +783,14 @@ def run_partitioned(engine, pipelines: Optional[Sequence[Pipeline]], *,
         raise ValueError(f"backend must be one of {lindley.BACKENDS}, "
                          f"got {backend!r}")
     plan = ShardPlan.build(engine.n_dscs, engine.n_cpu, n_shards, engine.seed)
-    if processes is None:
+    if backend == "pallas":
+        if processes is not None and processes > 1:
+            raise ValueError(
+                f"backend='pallas' needs processes=1, got {processes}: "
+                "forked shard workers cannot use the accelerator the "
+                "parent process holds")
+        processes = 1
+    elif processes is None:
         processes = min(n_shards, os.cpu_count() or 1)
 
     if times is None:
@@ -800,11 +810,11 @@ def run_partitioned(engine, pipelines: Optional[Sequence[Pipeline]], *,
     if engine.faults is not None or tier_on or timeout_s is not None \
             or ov_on:
         if backend != "segmented":
-            warnings.warn(
-                f"backend={backend!r} has no effect: faults/tiering/"
+            raise ValueError(
+                f"backend={backend!r} cannot run here: faults/tiering/"
                 "deadline/overload runs take the shard-isolated fallback "
-                "(the classic event loop), not the Lindley fast path",
-                UserWarning, stacklevel=3)
+                "(the classic event loop on the host), not the Lindley "
+                "fast path")
         return _run_shard_isolated(engine, pipelines, times, plan,
                                    processes, timeout_s,
                                    overload=ov if ov_on else None)

@@ -75,13 +75,7 @@ def moe_ffn_ep(x: jax.Array, gate_w: jax.Array, w1: jax.Array, w3: jax.Array,
               (batch_axes[0] if batch_axes else None))
     specs = dict(in_specs=(bspec, P(), P(ep_axis), P(ep_axis), P(ep_axis)),
                  out_specs=(bspec, P()))
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(body, mesh=mesh, check_vma=False, **specs)
-    else:
-        # older jax: shard_map lives in jax.experimental and the replication
-        # check is spelled check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-        fn = _shard_map(body, mesh=mesh, check_rep=False, **specs)
+    fn = jax.shard_map(body, mesh=mesh, check_vma=False, **specs)
     return fn(x, gate_w, w1, w3, w2)
 
 

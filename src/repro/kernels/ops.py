@@ -7,6 +7,7 @@ the same calls compile to Mosaic.
 from __future__ import annotations
 
 import jax
+import numpy as np
 
 from repro.kernels.systolic_matmul import systolic_matmul
 from repro.kernels.flash_attention import flash_attention
@@ -14,7 +15,7 @@ from repro.kernels.vector_engine import (fused_affine_act, quantize_int8,
                                          dequantize_int8)
 from repro.kernels.rglru import rglru_scan
 from repro.kernels.ssd import ssd_scan
-from repro.kernels.lindley import lindley_scan
+from repro.kernels.lindley import increments, lindley_scan
 
 
 def _interpret_default() -> bool:
@@ -85,14 +86,15 @@ def ssd(x, dt, A, Bm, Cm, *, chunk=128, interpret=None):
 
 
 def lindley(t, s, *, br=128, bd=128, interpret=None):
-    """Batched FCFS service starts in float64 (queue-sim precision).
+    """Batched FCFS service starts: arrivals ``t`` and services ``s``
+    (R, W) float64 -> starts (R, W) float64 numpy.
 
-    x64 is enabled only for this call — the engine's byte-identity
-    gates need exact fp64, but flipping the global default dtype would
-    leak into every other kernel and model.
+    The host forms the increments and the starts in float64; the device
+    carries the waiting time in float32, within the bound of
+    :func:`repro.kernels.lindley.error_bound`.
     """
-    from jax.experimental import enable_x64
-    with enable_x64():
-        return lindley_scan(t, s, br=br, bd=bd,
-                            interpret=_interpret_default()
-                            if interpret is None else interpret)
+    t = np.asarray(t, dtype=np.float64)
+    a = increments(t, np.asarray(s, dtype=np.float64)).astype(np.float32)
+    w = lindley_scan(a, br=br, bd=bd, interpret=_interpret_default()
+                     if interpret is None else interpret)
+    return t + np.asarray(w, dtype=np.float64)

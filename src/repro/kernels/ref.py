@@ -1,10 +1,12 @@
-"""Pure-jnp oracles for every Pallas kernel (shape/dtype-sweep targets)."""
+"""Pure-jnp oracles for every Pallas kernel (shape/dtype-sweep targets);
+the Lindley oracle is float64 numpy."""
 from __future__ import annotations
 
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import layers as L
 from repro.kernels.systolic_matmul import _ACTS
@@ -67,8 +69,10 @@ def ssd_ref(x, dt, A, Bm, Cm, *, chunk):
 
 
 def lindley_ref(t, s):
-    """Batched FCFS Lindley starts: t/s (R, W) -> start (R, W)."""
-    c = jnp.cumsum(s, axis=1)
-    prev = c - s
-    m = jax.lax.cummax(t - prev, axis=1)
-    return jnp.maximum(t, m + prev)
+    """Batched FCFS Lindley starts: t/s (R, W) -> start (R, W), in
+    float64 numpy (the precision the simulator's host backends keep)."""
+    t = np.asarray(t, dtype=np.float64)
+    s = np.asarray(s, dtype=np.float64)
+    prev = np.cumsum(s, axis=1) - s
+    m = np.maximum.accumulate(t - prev, axis=1)
+    return np.maximum(t, m + prev)

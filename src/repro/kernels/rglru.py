@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _rglru_kernel(x_ref, gx_ref, ga_ref, la_ref, h0_ref, o_ref, h_ref, *,
                   bs: int, c: float):
@@ -64,7 +62,7 @@ def rglru_scan(x: jax.Array, gx: jax.Array, ga: jax.Array, log_a: jax.Array,
         out_specs=pl.BlockSpec((bb, bs, bw), blk),
         out_shape=jax.ShapeDtypeStruct((B, S, W), x.dtype),
         scratch_shapes=[pltpu.VMEM((bb, bw), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, gx, ga, log_a.reshape(1, W), h0)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, hout_ref,
                 state_ref, *, Q: int, nc: int):
@@ -103,7 +101,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
             jax.ShapeDtypeStruct((B * H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xb, dtb, a2, bb, cb)

@@ -10,12 +10,7 @@ import jax
 
 
 def _mesh_kwargs(n_axes: int) -> dict:
-    # jax.sharding.AxisType only exists on newer jax; older versions take no
-    # axis_types argument and default every axis to Auto anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

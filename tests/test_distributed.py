@@ -113,9 +113,8 @@ _EP_SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.models.layers import moe_ffn
     from repro.distributed.moe_ep import moe_ffn_ep
-    _at = getattr(jax.sharding, "AxisType", None)
     mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         **({"axis_types": (_at.Auto,) * 2} if _at else {}))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     key = jax.random.PRNGKey(0)
     B, S, D, E, F, K = 4, 8, 16, 8, 32, 2
     ks = jax.random.split(key, 5)

@@ -58,3 +58,16 @@ def test_full_smoke_sweep_is_deterministic_across_interpreters():
 
     a, b = sweep(), sweep()
     assert a == b, "smoke sweep differs between two fresh interpreters"
+
+
+def test_montecarlo_seed_runs_never_reach_the_kernels():
+    """Seed subprocesses run figures only: the kernel rows of an
+    unfiltered ``benchmarks.run`` need JAX and the accelerator, which
+    concurrent ``--jobs`` would contend for."""
+    from benchmarks.montecarlo import seed_command
+    cmd = seed_command(3, "", smoke=True)
+    only = cmd[cmd.index("--only") + 1]
+    assert only == "fig" and "--smoke" in cmd
+    assert all(only in f.__name__ for f in ALL_FIGURES)
+    cmd = seed_command(3, "fig19", smoke=False)
+    assert cmd[cmd.index("--only") + 1] == "fig19"

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.lindley import error_bound
 
 KEY = jax.random.PRNGKey(7)
 
@@ -107,23 +108,24 @@ def test_rglru_kernel(b, s, w):
 # NOT slow-marked.
 @pytest.mark.parametrize("r,w", [(3, 17), (128, 128), (200, 300), (1, 1)])
 def test_lindley_kernel_vs_ref(r, w):
+    """The float32 device solve stays within its stated absolute bound
+    of the float64 numpy oracle, element by element."""
     rng = np.random.default_rng(11)
     t = np.sort(rng.uniform(0.0, 100.0, size=(r, w)), axis=1)
     s = rng.uniform(1e-3, 4.0, size=(r, w))
-    got = np.asarray(ops.lindley(t, s))
-    from jax.experimental import enable_x64
-    with enable_x64():
-        want = np.asarray(ref.lindley_ref(jnp.asarray(t), jnp.asarray(s)))
+    got = ops.lindley(t, s)
+    want = ref.lindley_ref(t, s)
     assert got.dtype == np.float64
-    np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-12)
+    assert np.all(np.abs(got - want) <= error_bound(t, s))
 
 
 @pytest.mark.parametrize("seed", [0, 7])
 @pytest.mark.parametrize("nserv,n", [(6, 500), (1, 700), (40, 64)])
 def test_lindley_kernel_bit_equal_to_numpy_backend(seed, nserv, n):
-    """Interpret-mode Pallas output must be byte-for-byte the segmented
-    numpy backend (same fp64 ops in the same order) — the property that
-    lets ``backend='pallas'`` reuse the golden traces unchanged."""
+    """``backend='pallas'`` through the segmented flat layout agrees
+    with the float64 ``segmented`` backend within the kernel's error
+    bound on every request (it is no longer bit-equal: the device works
+    in float32), and is exact at idle starts."""
     from repro.core import lindley as core_lindley
 
     rng = np.random.default_rng(seed)
@@ -138,19 +140,35 @@ def test_lindley_kernel_bit_equal_to_numpy_backend(seed, nserv, n):
         start = np.empty(n)
         fin = np.empty(n)
         core_lindley.solve_segments(seg, t, s, start, fin, backend=backend)
-        out[backend] = (start.tobytes(), fin.tobytes())
-    assert out["segmented"] == out["pallas"]
+        out[backend] = (start, fin)
+    bound = core_lindley.segment_error_bound(seg, t, s)
+    for col in (0, 1):
+        diff = np.abs(out["pallas"][col] - out["segmented"][col])
+        assert np.all(diff <= bound)
+    idle = out["segmented"][0] == t
+    assert np.array_equal(out["pallas"][0][idle], t[idle])
 
 
 def test_lindley_x64_scoped_to_the_call():
-    """ops.lindley returns exact float64 without flipping the global x64
-    default for the rest of the process."""
+    """ops.lindley returns float64 starts without turning on x64 for the
+    call or the process: the global default dtype stays float32."""
     t = np.array([[0.0, 0.5, 1.0]])
     s = np.array([[1.0, 1.0, 1.0]])
-    got = np.asarray(ops.lindley(t, s))
+    got = ops.lindley(t, s)
     assert got.dtype == np.float64
     np.testing.assert_array_equal(got, np.array([[0.0, 1.0, 2.0]]))
+    assert not jax.config.jax_enable_x64
     assert jnp.asarray(1.5).dtype == jnp.float32
+
+
+def test_lindley_error_bound_resets_at_idle_starts():
+    """The bound does not grow with simulated time: a queue that idles
+    before every arrival has no float32 error at all, however late."""
+    t = np.arange(1, 6, dtype=np.float64)[None, :] * 1e6
+    s = np.full_like(t, 0.5)
+    b = error_bound(t, s)
+    assert np.all(b < 1e-6)
+    np.testing.assert_array_equal(ops.lindley(t, s), t)
 
 
 @pytest.mark.parametrize("b,s,h,p,g,n,chunk", [
@@ -170,3 +188,30 @@ def test_ssd_kernel(b, s, h, p, g, n, chunk):
                                rtol=1e-3, atol=1e-3)
     np.testing.assert_allclose(np.asarray(hf), np.asarray(hfr),
                                rtol=1e-3, atol=1e-3)
+
+
+def test_compile_cache_is_fixed_in_the_checkout_or_placed_by_env(
+        monkeypatch):
+    """Entry points keep JAX's compile cache in one fixed, git-ignored
+    directory of the checkout, unless JAX_COMPILATION_CACHE_DIR places
+    it; importing sets nothing."""
+    import importlib
+    import pathlib
+
+    from repro import jax_cache
+    before = jax.config.jax_compilation_cache_dir
+    importlib.reload(jax_cache)
+    assert jax.config.jax_compilation_cache_dir == before
+    root = pathlib.Path(__file__).resolve().parent.parent
+    assert jax_cache.CACHE_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(root / "x"))
+        jax_cache.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        jax_cache.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(
+            jax_cache.CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -143,16 +143,41 @@ def test_lindley_backends_are_bit_identical(seed):
 
 
 def test_pallas_backend_is_bit_identical():
-    """Interpret-mode Pallas solve of a whole sharded run matches the
-    segmented numpy backend byte-for-byte (small config: interpret mode
-    trades speed for exactness)."""
+    """Interpret-mode Pallas solve of a whole sharded run agrees with the
+    segmented numpy backend within the kernel's float32 error bound,
+    queue by queue (small config: interpret mode is slow).  It is no
+    longer bit-identical: the device carries waiting times in float32.
+    Where no hedge decision flips, both sides see the same CPU queues and
+    every CPU copy lies within its own bound too."""
+    from repro.core.engine import _placement
+    from repro.core.lindley import fcfs_queues, segment_error_bound
+
     cfg = {**make_config(1), "tier": None, "faults": None,
            "timeout_s": None, "duration_s": 1.0}
     es, ts = run_cfg(cfg, 2, backend="segmented")
     assert es.last_shard_stats["path"] == "partitioned"
     ep, tp = run_cfg(cfg, 2, backend="pallas")
-    assert_traces_identical(ts, tp)
-    assert es._qstate == ep._qstate
+    assert np.array_equal(ts.arrival, tp.arrival)
+    accel = ~np.isnan(ts.dscs_finish)
+    assert np.array_equal(accel, ~np.isnan(tp.dscs_finish))
+    rids, seg, t, s, start = fcfs_queues(
+        _placement(cfg["n_dscs"], ts.n), ts.arrival, ts.dscs_finish,
+        cfg["n_dscs"])
+    bound = segment_error_bound(seg, t, s)
+    assert np.all(np.abs(tp.dscs_finish[rids] - ts.dscs_finish[rids])
+                  <= bound)
+    flipped = (ts.hedged != tp.hedged)[rids]
+    assert np.all(np.abs(start - t - cfg["hedge"])[flipped]
+                  <= bound[flipped])
+    if flipped.any():
+        return
+    disp = ts.arrival + np.where(accel, cfg["hedge"] or 0.0, 0.0)
+    rids, seg, t, s, _ = fcfs_queues(
+        cpu_affinity(cfg["n_dscs"], cfg["n_cpu"], ts.n), disp,
+        ts.cpu_finish, cfg["n_cpu"])
+    assert np.array_equal(np.isnan(ts.cpu_finish), np.isnan(tp.cpu_finish))
+    assert np.all(np.abs(tp.cpu_finish[rids] - ts.cpu_finish[rids])
+                  <= segment_error_bound(seg, t, s))
 
 
 def test_unknown_backend_is_rejected():
@@ -370,20 +395,27 @@ def test_fallback_timeout_only_goodput():
 
 
 def test_fallback_warns_when_backend_is_ignored():
-    """ISSUE 10 satellite: fallback runs (faults/tiering/deadline/overload)
-    never reach the Lindley fast path, so a non-default ``backend=`` is a
-    no-op — the engine must say so instead of silently ignoring it."""
-    import warnings
+    """Fallback runs (faults/tiering/deadline/overload) never reach the
+    Lindley fast path, so ``backend='pallas'`` there raises instead of
+    quietly running on the host; the default backend runs.  So does a
+    single-shard run (the classic loop), and ``pallas`` with more than
+    one worker process (a forked child cannot use its parent's chip)."""
     eng = ClusterEngine(n_dscs=4, n_cpu=4, seed=2,
                         faults=FaultPlan(drive_mtbf_s=5.0, drive_mttr_s=1.0))
-    with pytest.warns(UserWarning, match="backend='pallas' has no effect"):
+    with pytest.raises(ValueError, match="backend='pallas' cannot run"):
         eng.run_sharded(PIPES, arrivals=PoissonProcess(rate=50.0),
                         duration_s=2.0, n_shards=2, backend="pallas")
-    # the default backend name stays silent on the same fallback run
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        eng.run_sharded(PIPES, arrivals=PoissonProcess(rate=50.0),
-                        duration_s=2.0, n_shards=2, backend="segmented")
+    tr = eng.run_sharded(PIPES, arrivals=PoissonProcess(rate=50.0),
+                         duration_s=2.0, n_shards=2, backend="segmented")
+    assert tr.n > 0
+    plain = ClusterEngine(n_dscs=4, n_cpu=4, seed=2)
+    with pytest.raises(ValueError, match="n_shards=1"):
+        plain.run_sharded(PIPES, arrivals=PoissonProcess(rate=50.0),
+                          duration_s=2.0, n_shards=1, backend="pallas")
+    with pytest.raises(ValueError, match="processes=1"):
+        plain.run_sharded(PIPES, arrivals=PoissonProcess(rate=50.0),
+                          duration_s=2.0, n_shards=2, processes=2,
+                          backend="pallas")
 
 
 def test_tiny_run_with_empty_shards():

@@ -1,0 +1,64 @@
+"""Compile the main path's Pallas kernels at real widths for a described
+TPU v5e (no chip needed: the TPU compiler runs on the host).
+
+Catches what interpret mode cannot: element types the chip lacks (the
+float64 Lindley kernel was refused here), slices not aligned to the
+tiling, and more VMEM than a kernel may use.  The topology is described
+inside a fixture, never at import: only one process may load the TPU
+library, and pytest-xdist workers all import this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.lindley import lindley_scan
+from repro.kernels.systolic_matmul import systolic_matmul
+from repro.kernels.vector_engine import fused_affine_act
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip cannot be read back from the
+    # persistent cache without one; keep them out of it
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    return lowered.compile()
+
+
+def test_lindley_kernel_compiles_at_the_10m_bucket(one_chip):
+    """One shard's drive bucket of the 10^7-request, 1024-drive cell:
+    128 queues of depth 16384, float32."""
+    _compile(lambda a: lindley_scan(a, interpret=False), one_chip,
+             (128, 16384))
+
+
+@pytest.mark.parametrize("precision", ["default", "float32"])
+@pytest.mark.parametrize("m,k,n", [(12544, 256, 64),     # ResNet-50 stem
+                                   (49, 4608, 512)])     # layer-4 3x3 conv
+def test_systolic_matmul_compiles_at_resnet50_shapes(one_chip, m, k, n,
+                                                     precision):
+    with jax.default_matmul_precision(precision):
+        _compile(lambda x, w: systolic_matmul(x, w, interpret=False),
+                 one_chip, (m, k), (k, n))
+
+
+def test_fused_affine_act_compiles_at_a_224_image(one_chip):
+    """f1 normalization of one 224x224x3 request."""
+    _compile(lambda x, s, b: fused_affine_act(x, s, b, interpret=False),
+             one_chip, (1, 150528), (150528,), (150528,))
