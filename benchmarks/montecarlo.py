@@ -51,10 +51,8 @@ def ci95(values: Sequence[float]) -> Tuple[float, Optional[float]]:
 
 def seed_command(seed: int, only: str, smoke: bool) -> List[str]:
     """The ``benchmarks.run`` command line for one seed.  It always
-    filters to figures (every figure is named ``fig*``): the kernel rows
-    that an unfiltered run adds are seed-independent timings that need
-    JAX and the accelerator, which concurrent seed processes would
-    contend for."""
+    filters to figures (every figure is named ``fig*``): the roofline
+    rows that an unfiltered run adds do not depend on the seed."""
     cmd = [sys.executable, "-m", "benchmarks.run", "--json",
            "--seed", str(seed), "--only", only or "fig"]
     if smoke:

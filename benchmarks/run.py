@@ -1,6 +1,6 @@
-"""Benchmark harness: one function per paper table/figure, plus kernel
-micro-benchmarks and the roofline summary.  Prints ``name,us_per_call,
-derived`` CSV (for analytic figures the middle column is the metric value),
+"""Benchmark harness: one function per paper table/figure, plus the
+roofline summary.  Prints ``name,us_per_call,derived`` CSV (for analytic
+figures the middle column is the metric value),
 or a ``figures/v2`` JSON envelope ``{schema, seed, smoke, rows}`` with
 ``--json`` — each row is ``{name, value, derived, ci95}`` where ``ci95``
 is null for a single run and a ``[mean, halfwidth]`` pair when emitted by
@@ -18,59 +18,6 @@ import argparse
 import json
 import sys
 import time
-
-
-def _kernel_micro():
-    """Pallas kernels (interpret mode on CPU): wall-time per call + checksum
-    against the ref oracle."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.kernels import ops, ref
-
-    rows = []
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (256, 256), jnp.float32)
-    w = jax.random.normal(key, (256, 256), jnp.float32)
-
-    def timed(name, fn, reference):
-        out = fn()                       # compile+warm
-        t0 = time.perf_counter()
-        out = fn()
-        jax.block_until_ready(out)
-        us = (time.perf_counter() - t0) * 1e6
-        first = lambda v: v[0] if isinstance(v, (tuple, list)) else v
-        err = float(np.max(np.abs(np.asarray(first(out), np.float64)
-                                  - np.asarray(first(reference), np.float64))))
-        rows.append((f"kernel/{name}", us, f"max_err={err:.2e}"))
-
-    timed("systolic_matmul_256", lambda: ops.matmul(x, w),
-          ref.matmul_ref(x, w))
-    q = jax.random.normal(key, (1, 4, 128, 64), jnp.float32)
-    k = jax.random.normal(key, (1, 2, 128, 64), jnp.float32)
-    v = jax.random.normal(key, (1, 2, 128, 64), jnp.float32)
-    timed("flash_attention_128", lambda: ops.attention(q, k, v, bq=64, bk=64),
-          ref.attention_ref(q, k, v))
-    s = jax.random.normal(key, (256,))
-    b = jax.random.normal(key, (256,))
-    timed("vector_engine_affine", lambda: ops.affine_act(x, s, b, act="gelu"),
-          ref.affine_act_ref(x, s, b, act="gelu"))
-    xr = jax.random.normal(key, (2, 64, 128)) * 0.1
-    la = jax.random.normal(key, (128,))
-    h0 = jnp.zeros((2, 128))
-    timed("rglru_scan", lambda: ops.rglru(xr, xr, xr, la, h0),
-          ref.rglru_ref(xr, xr, xr, la, h0))
-    xs = jax.random.normal(key, (1, 128, 2, 16)) * 0.3
-    dt = jax.nn.softplus(jax.random.normal(key, (1, 128, 2)))
-    A = -jnp.exp(jax.random.normal(key, (2,)) * 0.3)
-    Bm = jax.random.normal(key, (1, 128, 1, 8)) * 0.3
-    timed("ssd_scan", lambda: ops.ssd(xs, dt, A, Bm, Bm, chunk=32),
-          ref.ssd_ref(xs, dt, A, Bm, Bm, chunk=32))
-    rng = np.random.default_rng(3)
-    tq = np.sort(rng.uniform(0.0, 50.0, size=(64, 128)), axis=1)
-    sq = rng.uniform(1e-3, 2.0, size=(64, 128))
-    timed("lindley_scan", lambda: ops.lindley(tq, sq), ref.lindley_ref(tq, sq))
-    return rows
 
 
 def _roofline_summary():
@@ -145,10 +92,6 @@ def main(argv=None) -> None:
             emit(name, val, derived)
         emit(f"{fig.__name__}/wall", dt, "us")
     if not args.only:
-        from repro.jax_cache import use_compile_cache
-        use_compile_cache()
-        for name, us, derived in _kernel_micro():
-            emit(name, us, derived)
         for name, val, derived in _roofline_summary():
             emit(name, val, derived)
     if args.as_json:

@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.energy import pipeline_energy_j
 from repro.core.function import Pipeline, standard_pipeline
@@ -89,6 +90,7 @@ class DSCSExecutor:
         self.platform = PLATFORMS[platform]
         self.lm = LatencyModel(seed=seed)
         self.image_size = image_size
+        self.calls = 0
         key = jax.random.PRNGKey(seed)
         if workload_name in _MODEL_BUILDERS:
             init, apply, kw = _MODEL_BUILDERS[workload_name]
@@ -99,9 +101,11 @@ class DSCSExecutor:
             accel = self.platform.kind == "dsa"
 
             def infer(arrays, request):
-                x = (_preprocess_vector_engine(request, accel)
-                     if request.dtype == jnp.uint8 else request)
-                return apply(rebuild(arrays), x, use_kernel=accel)
+                with jax.named_scope("f1"):
+                    x = (_preprocess_vector_engine(request, accel)
+                         if request.dtype == jnp.uint8 else request)
+                with jax.named_scope("f2"):
+                    return apply(rebuild(arrays), x, use_kernel=accel)
             self._infer = jax.jit(infer)
         elif workload_name == "credit_risk":
             self.params = jax.random.normal(key, (200, 1)) * 0.1
@@ -130,21 +134,31 @@ class DSCSExecutor:
         return self._infer.lower(self._arrays, request)
 
     def __call__(self, request: jax.Array) -> ExecutionReport:
+        """Serve one request.  Its host work shows in a profiler trace as
+        the spans ``f1f2`` (dispatch of the f1+f2 program), ``f3`` (the
+        top-1's dispatch) and ``account`` (the analytic latency and energy
+        model), each carrying the call's number as ``call``."""
         accel = self.platform.kind == "dsa"
         name = self.pipeline.name
+        self.calls += 1
         # f1 — pre-process (vector engine), f2 — inference (systolic
         # kernels on the DSA path)
-        if name in _MODEL_BUILDERS:
-            y = self._infer(self._arrays, request)
-        else:
-            y = self._apply(self.params, request)
+        with TraceAnnotation("f1f2", call=self.calls):
+            if name in _MODEL_BUILDERS:
+                y = self._infer(self._arrays, request)
+            else:
+                y = self._apply(self.params, request)
         # f3 — post/notify
-        if y.ndim >= 2 and y.shape[-1] > 1:
-            result = jnp.argmax(y, axis=-1)
-        else:
-            result = y
-        lat = self.lm.pipeline_breakdown(self.platform, self.pipeline.workload)
-        en = pipeline_energy_j(self.lm, self.platform, self.pipeline.workload)
+        with TraceAnnotation("f3", call=self.calls):
+            if y.ndim >= 2 and y.shape[-1] > 1:
+                result = jnp.argmax(y, axis=-1)
+            else:
+                result = y
+        with TraceAnnotation("account", call=self.calls):
+            lat = self.lm.pipeline_breakdown(self.platform,
+                                             self.pipeline.workload)
+            en = pipeline_energy_j(self.lm, self.platform,
+                                   self.pipeline.workload)
         return ExecutionReport(result=result, latency_breakdown=lat,
                                energy_breakdown=en,
                                platform=self.platform.name, accelerated=accel,

@@ -104,5 +104,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qr, k, v)
     return out.reshape(B, H, Sq, D)

@@ -91,6 +91,7 @@ def lindley_scan(a: jax.Array, *, br: int = 128, bd: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="lindley_scan",
     )(ap)
     return out.T[:R, :W]
 

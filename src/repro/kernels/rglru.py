@@ -65,4 +65,5 @@ def rglru_scan(x: jax.Array, gx: jax.Array, ga: jax.Array, log_a: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="rglru_scan",
     )(x, gx, ga, log_a.reshape(1, W), h0)

@@ -104,6 +104,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xb, dtb, a2, bb, cb)
     return (y.reshape(B, H, S, P).transpose(0, 2, 1, 3),
             hfin.reshape(B, H, P, N))

@@ -58,6 +58,10 @@ def _matmul_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, act: str,
         o_ref[...] = acc.astype(out_dtype)
 
 
+def _matmul_kernel_no_bias(x_ref, w_ref, o_ref, acc_ref, **kw):
+    _matmul_kernel(x_ref, w_ref, None, o_ref, acc_ref, **kw)
+
+
 @functools.partial(jax.jit, static_argnames=("act", "bm", "bn", "bk",
                                              "out_dtype", "interpret"))
 def systolic_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
@@ -83,9 +87,7 @@ def systolic_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
         args.append(b.reshape(1, N))
 
     kernel = functools.partial(
-        _matmul_kernel if b is not None else
-        (lambda x_ref, w_ref, o_ref, acc_ref, **kw:
-         _matmul_kernel(x_ref, w_ref, None, o_ref, acc_ref, **kw)),
+        _matmul_kernel if b is not None else _matmul_kernel_no_bias,
         act=act, nk=nk, out_dtype=out_dtype)
 
     return pl.pallas_call(
@@ -98,4 +100,5 @@ def systolic_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="systolic_matmul",
     )(*args)

@@ -49,6 +49,7 @@ def fused_affine_act(x: jax.Array, scale: jax.Array, bias: jax.Array, *,
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
+        name="fused_affine_act",
     )(x, scale.reshape(1, N), bias.reshape(1, N))
 
 
@@ -75,6 +76,7 @@ def quantize_int8(x: jax.Array, *, bm: int = 256, interpret: bool = False):
         out_shape=[jax.ShapeDtypeStruct((M, N), jnp.int8),
                    jax.ShapeDtypeStruct((M, 1), jnp.float32)],
         interpret=interpret,
+        name="quantize_int8",
     )(x)
 
 
@@ -96,4 +98,5 @@ def dequantize_int8(q: jax.Array, scales: jax.Array, *, out_dtype=jnp.float32,
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
+        name="dequantize_int8",
     )(q, scales)

@@ -7,6 +7,7 @@ Convolutions can execute through the DSA path: im2col patches ->
 """
 from __future__ import annotations
 
+import itertools
 import math
 from functools import partial
 from typing import Any, Dict, List, Tuple
@@ -19,23 +20,37 @@ Pytree = Any
 
 
 def conv2d(x: jax.Array, w: jax.Array, stride: int = 1,
-           use_kernel: bool = False) -> jax.Array:
-    """x (B,H,W,C); w (kh,kw,C,O), SAME padding."""
-    if not use_kernel:
-        return lax.conv_general_dilated(
-            x, w, (stride, stride), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    kh, kw, c, o = w.shape
-    patches = lax.conv_general_dilated_patches(
-        x, (kh, kw), (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))      # (B,H',W',kh*kw*C)
-    B, H2, W2, K = patches.shape
-    m = B * H2 * W2
-    from repro.kernels import ops
-    # patches are (C, kh, kw)-ordered along the feature dim
-    w2 = jnp.transpose(w, (2, 0, 1, 3)).reshape(K, o)
-    out = ops.matmul_padded(patches.reshape(m, K), w2)
-    return out.reshape(B, H2, W2, o)
+           use_kernel: bool = False, *, name: str) -> jax.Array:
+    """x (B,H,W,C); w (kh,kw,C,O), SAME padding, in the named scope
+    ``name``.  On the kernel path its steps have scopes of their own:
+    ``im2col`` (the patches as an (M, K) matrix), ``weights`` (``w`` laid
+    out as (K, O)) and ``gemm`` (the padded systolic call)."""
+    with jax.named_scope(name):
+        if not use_kernel:
+            return lax.conv_general_dilated(
+                x, w, (stride, stride), "SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        kh, kw, c, o = w.shape
+        with jax.named_scope("im2col"):
+            patches = lax.conv_general_dilated_patches(
+                x, (kh, kw), (stride, stride), "SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))  # (B,H',W',kh*kw*C)
+            B, H2, W2, K = patches.shape
+            patches = patches.reshape(B * H2 * W2, K)
+        from repro.kernels import ops
+        with jax.named_scope("weights"):
+            # patches are (C, kh, kw)-ordered along the feature dim
+            w2 = jnp.transpose(w, (2, 0, 1, 3)).reshape(K, o)
+        with jax.named_scope("gemm"):
+            return ops.matmul_padded(patches, w2).reshape(B, H2, W2, o)
+
+
+def _numbered(use_kernel: bool):
+    """``conv2d`` for one forward pass, each call in the scope ``conv<i>``,
+    ``i`` counting the calls in program order."""
+    n = itertools.count()
+    return lambda x, w, stride: conv2d(x, w, stride, use_kernel,
+                                       name=f"conv{next(n)}")
 
 
 def _init_conv(key, kh, kw, c, o):
@@ -80,15 +95,16 @@ def resnet50_init(key, *, width: float = 1.0, classes: int = 1000) -> Pytree:
 
 
 def resnet50_apply(p: Pytree, x: jax.Array, use_kernel: bool = False) -> jax.Array:
-    h = jax.nn.relu(conv2d(x, p["stem"], 2, use_kernel))
+    conv = _numbered(use_kernel)
+    h = jax.nn.relu(conv(x, p["stem"], 2))
     h = lax.reduce_window(h, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
                           "SAME")
     for blk in p["blocks"]:
         s = blk["stride"]
-        r = conv2d(h, blk["proj"], s, use_kernel) if "proj" in blk else h
-        h2 = jax.nn.relu(conv2d(h, blk["c1"], 1, use_kernel))
-        h2 = jax.nn.relu(conv2d(h2, blk["c2"], s, use_kernel))
-        h2 = conv2d(h2, blk["c3"], 1, use_kernel)
+        r = conv(h, blk["proj"], s) if "proj" in blk else h
+        h2 = jax.nn.relu(conv(h, blk["c1"], 1))
+        h2 = jax.nn.relu(conv(h2, blk["c2"], s))
+        h2 = conv(h2, blk["c3"], 1)
         h = jax.nn.relu(h2 + r)
     h = jnp.mean(h, axis=(1, 2))
     return h @ p["head"]
@@ -122,17 +138,18 @@ def effnet_init(key, *, width: float = 1.0, classes: int = 1000) -> Pytree:
 
 
 def effnet_apply(p, x, use_kernel: bool = False):
-    h = jax.nn.silu(conv2d(x, p["stem"], 2, use_kernel))
+    conv = _numbered(use_kernel)
+    h = jax.nn.silu(conv(x, p["stem"], 2))
     for blk in p["blocks"]:
         inp = h
-        h2 = jax.nn.silu(conv2d(h, blk["expand"], 1, use_kernel))
+        h2 = jax.nn.silu(conv(h, blk["expand"], 1))
         h2 = jax.nn.silu(lax.conv_general_dilated(
             h2, blk["dw"], (blk["stride"],) * 2, "SAME",
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
             feature_group_count=h2.shape[-1]))
-        h2 = conv2d(h2, blk["project"], 1, use_kernel)
+        h2 = conv(h2, blk["project"], 1)
         h = h2 + inp if h2.shape == inp.shape else h2
-    h = jax.nn.silu(conv2d(h, p["head_conv"], 1, use_kernel))
+    h = jax.nn.silu(conv(h, p["head_conv"], 1))
     h = jnp.mean(h, axis=(1, 2))
     return h @ p["head"]
 
@@ -151,22 +168,23 @@ def fcn_init(key, *, width: float = 1.0, classes: int = 21) -> Pytree:
 
 
 def fcn_apply(p, x, use_kernel: bool = False):
+    conv = _numbered(use_kernel)
     bb = p["backbone"]
-    h = jax.nn.relu(conv2d(x, bb["stem"], 2, use_kernel))
+    h = jax.nn.relu(conv(x, bb["stem"], 2))
     h = lax.reduce_window(h, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
                           "SAME")
     for blk in bb["blocks"]:
         s = blk["stride"]
-        r = conv2d(h, blk["proj"], s, use_kernel) if "proj" in blk else h
-        h2 = jax.nn.relu(conv2d(h, blk["c1"], 1, use_kernel))
-        h2 = jax.nn.relu(conv2d(h2, blk["c2"], s, use_kernel))
-        h2 = conv2d(h2, blk["c3"], 1, use_kernel)
+        r = conv(h, blk["proj"], s) if "proj" in blk else h
+        h2 = jax.nn.relu(conv(h, blk["c1"], 1))
+        h2 = jax.nn.relu(conv(h2, blk["c2"], s))
+        h2 = conv(h2, blk["c3"], 1)
         h = jax.nn.relu(h2 + r)
-    h = conv2d(h, p["score"], 1, use_kernel)
+    h = conv(h, p["score"], 1)
     # bilinear-ish upsample back to input resolution
     H = x.shape[1]
     h = jax.image.resize(h, (h.shape[0], H, H, h.shape[-1]), "linear")
-    return conv2d(h, p["out"], 1, use_kernel)
+    return conv(h, p["out"], 1)
 
 
 # --------------------------------------------------------------------------
@@ -193,16 +211,17 @@ def yolov3_init(key, *, width: float = 1.0) -> Pytree:
 
 
 def yolov3_apply(p, x, use_kernel: bool = False):
+    conv = _numbered(use_kernel)
     act = lambda v: jax.nn.leaky_relu(v, 0.1)
-    h = act(conv2d(x, p["stem"], 1, use_kernel))
+    h = act(conv(x, p["stem"], 1))
     for stage in p["trunk"]:
-        h = act(conv2d(h, stage["down"], 2, use_kernel))
+        h = act(conv(h, stage["down"], 2))
         for c1, c2 in stage["res"]:
             r = h
-            h = act(conv2d(h, c1, 1, use_kernel))
-            h = act(conv2d(h, c2, 1, use_kernel))
+            h = act(conv(h, c1, 1))
+            h = act(conv(h, c2, 1))
             h = h + r
-    return conv2d(h, p["head"], 1, use_kernel)
+    return conv(h, p["head"], 1)
 
 
 # --------------------------------------------------------------------------

@@ -61,9 +61,9 @@ def test_full_smoke_sweep_is_deterministic_across_interpreters():
 
 
 def test_montecarlo_seed_runs_never_reach_the_kernels():
-    """Seed subprocesses run figures only: the kernel rows of an
-    unfiltered ``benchmarks.run`` need JAX and the accelerator, which
-    concurrent ``--jobs`` would contend for."""
+    """Seed subprocesses run figures only: the rows that an unfiltered
+    ``benchmarks.run`` adds besides the figures do not depend on the
+    seed."""
     from benchmarks.montecarlo import seed_command
     cmd = seed_command(3, "", smoke=True)
     only = cmd[cmd.index("--only") + 1]

@@ -62,3 +62,34 @@ def test_fused_affine_act_compiles_at_a_224_image(one_chip):
     """f1 normalization of one 224x224x3 request."""
     _compile(lambda x, s, b: fused_affine_act(x, s, b, interpret=False),
              one_chip, (1, 150528), (150528,), (150528,))
+
+
+@pytest.mark.parametrize("pipeline,size", [("asset_damage", 32),
+                                           ("ppe_detection", 64)])
+def test_one_named_kernel_per_convolution_in_the_served_program(
+        one_chip, monkeypatch, pipeline, size):
+    """The compiled f1+f2 program of a tiny vision function holds one
+    ``systolic_matmul`` kernel in each convolution's ``gemm`` scope and the
+    ``fused_affine_act`` kernel in f1: the names the device trace shows."""
+    import re
+
+    from repro.core.executor import DSCSExecutor
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    ex = DSCSExecutor(pipeline, image_size=size, width=0.125)
+    arrays = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+              for a in ex._arrays]
+    frame = jax.ShapeDtypeStruct((1, size, size, 3), jnp.uint8,
+                                 sharding=one_chip)
+    text = ex._infer.lower(arrays, frame).compile().as_text()
+    kernels = re.findall(r'%(\w+)\.\d+ = [^\n]*custom_call_target='
+                         r'"tpu_custom_call"[^\n]*op_name="jit\(infer\)/'
+                         r'([^"]*)"', text)
+    n_convs = sum(a.ndim == 4 for a in ex._arrays)
+    gemm = sorted(int(m.group(1)) for k, scope in kernels
+                  if k == "systolic_matmul"
+                  for m in [re.match(r"f2/conv(\d+)/gemm/", scope)] if m)
+    assert gemm == list(range(n_convs))
+    assert len(kernels) == n_convs + 1
+    assert [k for k, scope in kernels if scope.startswith("f1/")] == [
+        "fused_affine_act"]
