@@ -36,16 +36,22 @@ class ExecutionReport:
 
 
 def _preprocess_vector_engine(img: jax.Array, use_kernel: bool) -> jax.Array:
-    """f1: normalize + cast — the DSA vector engine's job."""
+    """f1: normalize + cast — the DSA vector engine's job.  The kernel
+    streams the frame one channel plane (H, W) per block, so its output is
+    laid out by plane: lane-dense for three channels, where a
+    channel-minor layout would fill 3 of 128 lanes."""
+    if use_kernel:
+        B, H, W, C = img.shape
+        planes = jnp.transpose(img, (0, 3, 1, 2)).reshape(B * C * H, W)
+        out = ops.affine_act(planes.astype(jnp.float32),
+                             jnp.full((W,), 1.0 / 127.5), jnp.full((W,), -1.0),
+                             act="none", bm=H)
+        return jnp.transpose(out.reshape(B, C, H, W), (0, 2, 3, 1))
     flat = img.reshape(img.shape[0], -1).astype(jnp.float32)
     n = flat.shape[1]
     scale = jnp.full((n,), 1.0 / 127.5)
     bias = jnp.full((n,), -1.0)
-    if use_kernel:
-        out = ops.affine_act(flat, scale, bias, act="none")
-    else:
-        out = flat * scale + bias
-    return out.reshape(img.shape)
+    return (flat * scale + bias).reshape(img.shape)
 
 
 _MODEL_BUILDERS: Dict[str, Tuple[Callable, Callable, dict]] = {

@@ -56,8 +56,9 @@ def attention(q, k, v, *, causal=True, window=0, bq=128, bk=128,
                            if interpret is None else interpret)
 
 
-def affine_act(x, scale, bias, *, act="none", out_dtype=None, interpret=None):
-    return fused_affine_act(x, scale, bias, act=act, out_dtype=out_dtype,
+def affine_act(x, scale, bias, *, act="none", out_dtype=None, bm=256,
+               interpret=None):
+    return fused_affine_act(x, scale, bias, act=act, out_dtype=out_dtype, bm=bm,
                             interpret=_interpret_default()
                             if interpret is None else interpret)
 

@@ -4,6 +4,9 @@ CPU-scale smoke/demo runs.
 
 Convolutions can execute through the DSA path: im2col patches ->
 ``kernels.ops.matmul`` (the systolic kernel) — the paper's compiler story.
+The patches are pure data movement (a pad and ``kh*kw`` strided slices,
+ordered ``(kh, kw, C)`` along K), so the HWIO weights are the (K, O)
+matrix as they lie.
 """
 from __future__ import annotations
 
@@ -19,12 +22,31 @@ from jax import lax
 Pytree = Any
 
 
+def im2col(x: jax.Array, kh: int, kw: int, stride: int) -> jax.Array:
+    """SAME-padded patches of x (B,H,W,C) as (B,H',W',kh*kw*C), ordered
+    (kh, kw, C) along the last axis: x padded once, then one strided slice
+    per kernel tap, concatenated.  Copies only, so exact in any precision."""
+    B, H, W, C = x.shape
+    (pt, pb), (pl, pr) = lax.padtype_to_pads((H, W), (kh, kw),
+                                             (stride, stride), "SAME")
+    xp = lax.pad(x, jnp.zeros((), x.dtype),
+                 ((0, 0, 0), (pt, pb, 0), (pl, pr, 0), (0, 0, 0)))
+    H2, W2 = -(-H // stride), -(-W // stride)
+    taps = [lax.slice(xp, (0, i, j, 0),
+                      (B, i + (H2 - 1) * stride + 1,
+                       j + (W2 - 1) * stride + 1, C),
+                      (1, stride, stride, 1))
+            for i in range(kh) for j in range(kw)]
+    return lax.concatenate(taps, 3)
+
+
 def conv2d(x: jax.Array, w: jax.Array, stride: int = 1,
            use_kernel: bool = False, *, name: str) -> jax.Array:
     """x (B,H,W,C); w (kh,kw,C,O), SAME padding, in the named scope
     ``name``.  On the kernel path its steps have scopes of their own:
-    ``im2col`` (the patches as an (M, K) matrix), ``weights`` (``w`` laid
-    out as (K, O)) and ``gemm`` (the padded systolic call)."""
+    ``im2col`` (:func:`im2col`'s patches as an (M, K) matrix),
+    ``weights`` (``w`` as (K, O): a reshape, since the patches share the
+    HWIO order) and ``gemm`` (the padded systolic call)."""
     with jax.named_scope(name):
         if not use_kernel:
             return lax.conv_general_dilated(
@@ -32,15 +54,12 @@ def conv2d(x: jax.Array, w: jax.Array, stride: int = 1,
                 dimension_numbers=("NHWC", "HWIO", "NHWC"))
         kh, kw, c, o = w.shape
         with jax.named_scope("im2col"):
-            patches = lax.conv_general_dilated_patches(
-                x, (kh, kw), (stride, stride), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"))  # (B,H',W',kh*kw*C)
+            patches = im2col(x, kh, kw, stride)
             B, H2, W2, K = patches.shape
             patches = patches.reshape(B * H2 * W2, K)
         from repro.kernels import ops
         with jax.named_scope("weights"):
-            # patches are (C, kh, kw)-ordered along the feature dim
-            w2 = jnp.transpose(w, (2, 0, 1, 3)).reshape(K, o)
+            w2 = w.reshape(K, o)
         with jax.named_scope("gemm"):
             return ops.matmul_padded(patches, w2).reshape(B, H2, W2, o)
 

@@ -271,3 +271,21 @@ def test_executor_runs_all_workloads():
         assert rep.latency_breakdown["total"] > 0
         assert rep.energy_breakdown["total"] > 0
         assert rep.accelerated
+
+
+@pytest.mark.parametrize("shape", [(1, 32, 48, 3), (2, 16, 16, 3)])
+def test_vector_engine_f1_by_channel_plane_keeps_every_value(shape):
+    """f1's kernel streams the frame one channel plane per block; each
+    element is the same as the kernel gives over the flat frame in one
+    block."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.executor import _preprocess_vector_engine
+    from repro.kernels import ops
+    img = jax.random.randint(jax.random.PRNGKey(3), shape, 0, 256
+                             ).astype(jnp.uint8)
+    flat = img.reshape(shape[0], -1).astype(jnp.float32)
+    n = flat.shape[1]
+    want = ops.affine_act(flat, jnp.full((n,), 1.0 / 127.5),
+                          jnp.full((n,), -1.0)).reshape(shape)
+    np.testing.assert_array_equal(_preprocess_vector_engine(img, True), want)

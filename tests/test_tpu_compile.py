@@ -59,9 +59,11 @@ def test_systolic_matmul_compiles_at_resnet50_shapes(one_chip, m, k, n,
 
 
 def test_fused_affine_act_compiles_at_a_224_image(one_chip):
-    """f1 normalization of one 224x224x3 request."""
-    _compile(lambda x, s, b: fused_affine_act(x, s, b, interpret=False),
-             one_chip, (1, 150528), (150528,), (150528,))
+    """f1 normalization of one 224x224x3 request: its three channel
+    planes as rows, a plane per block."""
+    _compile(lambda x, s, b: fused_affine_act(x, s, b, bm=224,
+                                              interpret=False),
+             one_chip, (3 * 224, 224), (224,), (224,))
 
 
 @pytest.mark.parametrize("pipeline,size", [("asset_damage", 32),
