@@ -16,6 +16,10 @@ from jax import lax
 
 from bench.refops import Conv, channels, conv, init_conv, matmul, normalize
 
+# the configuration keys, and their values, at which the CPU (Pallas in
+# interpret mode) serves and checks a request in seconds
+CPU_SIZE = {"width": 0.125, "image_size": 32}
+
 
 def _blocks(cfg):
     """(cin, mid, out, stride, has_proj) of every bottleneck block."""

@@ -16,6 +16,10 @@ import jax
 
 from bench.refops import Conv, channels, conv, init_conv, normalize
 
+# the configuration keys, and their values, at which the CPU (Pallas in
+# interpret mode) serves and checks a request in seconds
+CPU_SIZE = {"width": 0.125, "image_size": 64}
+
 
 def _stages(cfg):
     w = cfg["width"]

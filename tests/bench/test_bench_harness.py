@@ -1,6 +1,7 @@
 """The harness end to end on the CPU at tiny sizes: found by name, no
 fallback to the CPU, and ``correct`` false when the served path is
 broken underneath."""
+import gzip
 import json
 import os
 import shutil
@@ -12,14 +13,17 @@ from dataclasses import replace
 import jax.numpy as jnp
 import pytest
 
-from conftest import ROOT, write_json
+from conftest import ROOT, spec, write_json
 
 from bench import run
+from bench import trace as tr
+
+WHOLE_STEP = {"step_mfu", "device_idle_share", "invoke_host_ms"}
 
 
-def _run(root, workload, seed=2**31 + 5, trace=False):
+def _run(root, workload, seed=2**31 + 5, trace=False, seconds=0.3):
     cell = run.load_cell(root, workload)
-    return run.run_cell(cell, seed, 0.3, trace, require_accelerator=False,
+    return run.run_cell(cell, seed, seconds, trace, require_accelerator=False,
                         t_start=time.perf_counter())
 
 
@@ -66,6 +70,42 @@ def test_traced_run_reports_per_layer_metrics_it_can_read(tiny_root):
     assert set(res["metrics"]) == {"invoke_host_ms", "step_mfu"}
     assert res["device"]["window_s"] > 0
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_eight_in_flight_cell_is_correct(tiny_root):
+    """``resnet50-c8`` keeps 8 requests in flight: more than 8 are served
+    in a window, every sampled one agrees with the reference."""
+    res = _run(tiny_root, "resnet50-c8", seconds=1.0)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 8 and res["failed"] == 0
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in spec()["workloads"]])
+def test_every_cell_reports_the_whole_step_metrics(workload):
+    """Every cell, also one that a later PR adds, is given the whole
+    step's share of the peak, the device's idle share and the host's
+    invocation time among its per-layer metrics."""
+    names = {m["name"] for m in run.load_cell(ROOT, workload).per_layer}
+    assert WHOLE_STEP <= names, names
+
+
+def test_traced_eight_in_flight_run_reads_the_whole_step_metrics(
+        tiny_root, monkeypatch, tmp_path):
+    """A traced ``resnet50-c8`` run reads the three whole-step metrics.
+    The CPU's trace has no device plane, so the run is handed a recorded
+    chip trace in its place (``tests/bench/data/yolov3_tiny.xplane.pb.gz``,
+    another program's): only that each is read, and in its range, is
+    checked here."""
+    path = tmp_path / "yolov3_tiny.xplane.pb"
+    path.write_bytes(gzip.decompress(
+        (ROOT / "tests/bench/data/yolov3_tiny.xplane.pb.gz").read_bytes()))
+    recorded = tr.load(path)
+    monkeypatch.setattr(tr, "load", lambda *a, **k: recorded)
+    res = _run(tiny_root, "resnet50-c8", trace=True)
+    assert res["correct"], res["checks"]
+    assert WHOLE_STEP <= set(res["metrics"]), res["metrics"]
+    assert all(res["metrics"][m]["value"] > 0 for m in WHOLE_STEP)
+    assert res["metrics"]["device_idle_share"]["value"] < 100
 
 
 FAULTS = {

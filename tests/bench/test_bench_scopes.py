@@ -13,21 +13,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import ROOT, TINY
+from conftest import ROOT, config, spec
 
 from bench import scopes
 from bench import trace as tr
 from bench.run import load_module
 
 DATA = ROOT / "tests" / "bench" / "data"
-CONFIGS = {"resnet50": "resnet50-asset_damage",
-           "yolov3": "yolov3-ppe_detection"}
-
-
-def _cfg(model):
-    cfg = json.loads((ROOT / "bench" / "configs" /
-                      f"{CONFIGS[model]}.json").read_text())
-    return {**cfg, **TINY[model]}
+CONFIGS = [c["name"] for c in spec()["configs"]]
 
 
 SYNTHETIC_HLO = """\
@@ -176,14 +169,14 @@ def test_executor_writes_its_spans_with_the_call_number(tmp_path):
         assert all(b >= a for a, b, _ in sps)
 
 
-@pytest.mark.parametrize("model", sorted(CONFIGS))
-def test_compiled_program_scopes_every_convolution(model):
+@pytest.mark.parametrize("name", CONFIGS)
+def test_compiled_program_scopes_every_convolution(name):
     """Every convolution of the reference's count has its ``im2col``,
     ``weights`` and ``gemm`` scopes in the f1+f2 program, f1 has its own,
     and every instruction of the compiled program maps to a scope."""
     from repro.core.executor import DSCSExecutor
-    cfg = _cfg(model)
-    n = len(load_module(ROOT, "models", model).convs(cfg))
+    cfg = config(name, cpu=True)
+    n = len(load_module(ROOT, "models", cfg["model"]).convs(cfg))
     ex = DSCSExecutor(cfg["pipeline"], image_size=cfg["image_size"],
                       width=cfg["width"])
     s = cfg["image_size"]
@@ -201,10 +194,10 @@ def test_compiled_program_scopes_every_convolution(model):
     assert "f1" in smap.values()
 
 
-@pytest.mark.parametrize("model", sorted(CONFIGS))
-def test_scopes_leave_the_jaxpr_unchanged(model, monkeypatch):
+@pytest.mark.parametrize("name", CONFIGS)
+def test_scopes_leave_the_jaxpr_unchanged(name, monkeypatch):
     from repro.core.executor import DSCSExecutor
-    cfg = _cfg(model)
+    cfg = config(name, cpu=True)
     ex = DSCSExecutor(cfg["pipeline"], image_size=cfg["image_size"],
                       width=cfg["width"])
     s = cfg["image_size"]
@@ -252,7 +245,7 @@ PINNED = {"invoke_host_ms": 1.0709061538461544,
 
 def test_existing_metrics_read_what_they_read_before(tmp_path):
     t = tr.load(_unzip("yolov3_tiny.xplane.pb.gz", tmp_path))
-    run = _run_from_trace(t, _cfg("yolov3"))
+    run = _run_from_trace(t, config("yolov3-ppe_detection", cpu=True))
     got = {m: load_module(ROOT, "metrics", m).read(run) for m in PINNED}
     assert got == pytest.approx(PINNED, rel=1e-12)
 
@@ -269,7 +262,7 @@ def test_scoped_chip_trace_reads_the_new_metrics(tmp_path, monkeypatch):
     monkeypatch.setattr(scopes, "program_text", lambda cfg, ex=None: text)
     t = tr.load(path)
     assert set(t.kernels) == {None, "systolic_matmul", "fused_affine_act"}
-    run = _run_from_trace(t, _cfg("yolov3"))
+    run = _run_from_trace(t, config("yolov3-ppe_detection", cpu=True))
     got = {m: load_module(ROOT, "metrics", m).read(run)
            for m in ("f1_ms", "im2col_ms", "conv_roofline",
                      "systolic_roofline")}
