@@ -62,7 +62,9 @@ _MODEL_BUILDERS: Dict[str, Tuple[Callable, Callable, dict]] = {
     "clinical": (vision.fcn_init, vision.fcn_apply, {"width": 0.125}),
     "ppe_detection": (vision.yolov3_init, vision.yolov3_apply,
                       {"width": 0.125}),
-    "remote_sensing": (vision.vit_init, vision.vit_apply, {}),
+    # 16-pixel patches, so that the executor's default frames tile
+    "remote_sensing": (vision.vit_init, vision.vit_apply,
+                       {"width": 0.1, "patch": 16}),
 }
 
 
@@ -84,8 +86,11 @@ class DSCSExecutor:
     """Executes one Table I pipeline end-to-end in a chosen deployment.
 
     Vision pipelines run f1 and f2 as one jitted program.  ``width``
-    overrides the model's channel multiplier (``1.0`` is the published
-    width; the default is the reduced width in ``_MODEL_BUILDERS``).
+    serves the published model at that channel multiplier (``1.0`` is the
+    published width); left out, the reduced model of ``_MODEL_BUILDERS``.
+    For ViT (``remote_sensing``) the published model is ViT-H/14: width
+    ``1280 * width``, MLP ``5120 * width``, 16 heads, 32 layers, 14-pixel
+    patches, so ``image_size`` must be a multiple of 14.
     """
 
     def __init__(self, workload_name: str, *, platform: str = "DSCS-Serverless",
@@ -101,7 +106,7 @@ class DSCSExecutor:
         if workload_name in _MODEL_BUILDERS:
             init, apply, kw = _MODEL_BUILDERS[workload_name]
             if width is not None:
-                kw = {**kw, "width": width}
+                kw = {"width": width}
             self.params = init(key, **kw)
             self._arrays, rebuild = _split_arrays(self.params)
             accel = self.platform.kind == "dsa"

@@ -1,9 +1,11 @@
 """Blocked (flash-style) attention as a Pallas TPU kernel.
 
 Online-softmax over K/V blocks with fp32 VMEM accumulators; supports GQA
-(kv-head groups via BlockSpec index maps), causal masking and sliding
-windows.  Grid: (batch*heads, Sq/bq, Skv/bk) with the K/V dimension
-innermost and sequential — the same tiling the pure-JAX
+(kv-head groups via BlockSpec index maps), causal masking, sliding
+windows and lengths that are not a multiple of the block (keys padded up
+to a whole block get no weight; padded query rows are cut off).  Grid:
+(batch*heads, Sq/bq, Skv/bk) with the K/V dimension innermost and
+sequential — the same tiling the pure-JAX
 ``models.layers.blocked_attention`` oracle uses, so the two validate against
 each other across shapes/dtypes.
 """
@@ -22,8 +24,8 @@ NEG_INF = -1e30
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  scale: float, causal: bool, window: int, bq: int, bk: int,
-                  nk: int, out_dtype):
+                  scale: float, causal: bool, window: int, kv_len: int,
+                  bq: int, bk: int, nk: int, out_dtype):
     ik = pl.program_id(2)
     iq = pl.program_id(1)
 
@@ -45,6 +47,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         mask &= kpos <= qpos
     if window:
         mask &= (qpos - kpos) < window
+    if kv_len:                  # keys padded past the true length
+        mask &= kpos < kv_len
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]                               # (bq, 1)
@@ -68,23 +72,31 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0, bq: int = 128,
                     bk: int = 128, interpret: bool = False) -> jax.Array:
-    """q (B, H, Sq, D); k/v (B, KV, Skv, D) -> (B, H, Sq, D)."""
+    """q (B, H, Sq, D); k/v (B, KV, Skv, D) -> (B, H, Sq, D), any lengths
+    and head dim.  A block as long as its sequence takes it whole; a
+    shorter one that does not divide it is padded up to whole blocks."""
     B, H, Sq, D = q.shape
     KV, Skv = k.shape[1], k.shape[2]
     G = H // KV
     bq, bk = min(bq, Sq), min(bk, Skv)
-    assert Sq % bq == 0 and Skv % bk == 0
-    nk = Skv // bk
+    Sqp, Skp = -(-Sq // bq) * bq, -(-Skv // bk) * bk
+    if Sqp != Sq:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, Sqp - Sq), (0, 0)))
+    if Skp != Skv:
+        pad = ((0, 0), (0, 0), (0, Skp - Skv), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+    nk = Skp // bk
     scale = 1.0 / math.sqrt(D)
 
-    qr = q.reshape(B * H, Sq, D)
+    qr = q.reshape(B * H, Sqp, D)
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                               window=window, bq=bq, bk=bk, nk=nk,
-                               out_dtype=q.dtype)
+                               window=window,
+                               kv_len=Skv if Skp != Skv else 0,
+                               bq=bq, bk=bk, nk=nk, out_dtype=q.dtype)
 
     out = pl.pallas_call(
         kernel,
-        grid=(B * H, Sq // bq, nk),
+        grid=(B * H, Sqp // bq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, 1, bk, D),
@@ -95,7 +107,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                                        ik, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sqp, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -106,4 +118,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         interpret=interpret,
         name="flash_attention",
     )(qr, k, v)
-    return out.reshape(B, H, Sq, D)
+    return out.reshape(B, H, Sqp, D)[:, :, :Sq]

@@ -1,9 +1,11 @@
 """The paper's Table I vision models in JAX (ResNet-50, EfficientNet-B0-ish,
-FCN, YOLOv3, ViT), structurally faithful with a ``width`` multiplier for
-CPU-scale smoke/demo runs.
+FCN, YOLOv3, ViT-H/14), structurally faithful at published widths with a
+``width`` multiplier for CPU-scale smoke/demo runs.
 
 Convolutions can execute through the DSA path: im2col patches ->
 ``kernels.ops.matmul`` (the systolic kernel) — the paper's compiler story.
+ViT's dense layers run on the same kernel and its attention on the flash
+kernel.
 The patches are pure data movement (a pad and ``kh*kw`` strided slices,
 ordered ``(kh, kw, C)`` along K), so the HWIO weights are the (K, O)
 matrix as they lie.
@@ -17,6 +19,7 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 Pytree = Any
@@ -41,17 +44,20 @@ def im2col(x: jax.Array, kh: int, kw: int, stride: int) -> jax.Array:
 
 
 def conv2d(x: jax.Array, w: jax.Array, stride: int = 1,
-           use_kernel: bool = False, *, name: str) -> jax.Array:
-    """x (B,H,W,C); w (kh,kw,C,O), SAME padding, in the named scope
-    ``name``.  On the kernel path its steps have scopes of their own:
-    ``im2col`` (:func:`im2col`'s patches as an (M, K) matrix),
-    ``weights`` (``w`` as (K, O): a reshape, since the patches share the
-    HWIO order) and ``gemm`` (the padded systolic call)."""
+           use_kernel: bool = False, *, name: str,
+           b: jax.Array | None = None) -> jax.Array:
+    """x (B,H,W,C); w (kh,kw,C,O), SAME padding, plus the bias ``b`` (O,)
+    if given, in the named scope ``name``.  On the kernel path its steps
+    have scopes of their own: ``im2col`` (:func:`im2col`'s patches as an
+    (M, K) matrix), ``weights`` (``w`` as (K, O): a reshape, since the
+    patches share the HWIO order) and ``gemm`` (the padded systolic call,
+    the bias in its epilogue)."""
     with jax.named_scope(name):
         if not use_kernel:
-            return lax.conv_general_dilated(
+            y = lax.conv_general_dilated(
                 x, w, (stride, stride), "SAME",
                 dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            return y if b is None else y + b
         kh, kw, c, o = w.shape
         with jax.named_scope("im2col"):
             patches = im2col(x, kh, kw, stride)
@@ -61,15 +67,15 @@ def conv2d(x: jax.Array, w: jax.Array, stride: int = 1,
         with jax.named_scope("weights"):
             w2 = w.reshape(K, o)
         with jax.named_scope("gemm"):
-            return ops.matmul_padded(patches, w2).reshape(B, H2, W2, o)
+            return ops.matmul_padded(patches, w2, b).reshape(B, H2, W2, o)
 
 
 def _numbered(use_kernel: bool):
     """``conv2d`` for one forward pass, each call in the scope ``conv<i>``,
     ``i`` counting the calls in program order."""
     n = itertools.count()
-    return lambda x, w, stride: conv2d(x, w, stride, use_kernel,
-                                       name=f"conv{next(n)}")
+    return lambda x, w, stride, b=None: conv2d(x, w, stride, use_kernel,
+                                               name=f"conv{next(n)}", b=b)
 
 
 def _init_conv(key, kh, kw, c, o):
@@ -244,55 +250,163 @@ def yolov3_apply(p, x, use_kernel: bool = False):
 
 
 # --------------------------------------------------------------------------
-# ViT encoder (patch embeddings precomputed or raw image)
+# ViT-H/14 (Dosovitskiy et al. 2020, Table 1 "ViT-Huge"), width-scalable
 # --------------------------------------------------------------------------
 
-def vit_init(key, *, layers=4, d=128, heads=4, d_ff=256, patch=16,
-             classes=1000) -> Pytree:
-    ks = iter(jax.random.split(key, 8 + 8 * layers))
-    p = {"patch": jax.random.normal(next(ks), (patch * patch * 3, d)) * 0.02,
-         "pos": jax.random.normal(next(ks), (1024, d)) * 0.01,
-         "cls": jax.random.normal(next(ks), (1, 1, d)) * 0.02,
-         "head": jax.random.normal(next(ks), (d, classes)) * 0.02,
+def _fan_in(key, shape):
+    """N(0, 1/fan_in) weights; the fan-in is every axis but the last."""
+    return jax.random.normal(key, shape) * math.prod(shape[:-1]) ** -0.5
+
+
+def _small(key, shape):
+    return jax.random.normal(key, shape) * 0.02
+
+
+# the resolution the position table is laid out for: ViT's pre-training
+# resolution, and the Table I function's 224x224 input
+VIT_TABLE_IMAGE = 224
+
+
+def vit_init(key, *, width: float = 1.0, layers: int = 32, d: int = 1280,
+             heads: int = 16, d_ff: int = 5120, patch: int = 14,
+             classes: int = 1000) -> Pytree:
+    """ViT-H/14 with its width ``d`` and MLP ``d_ff`` scaled by ``width``
+    and its ``heads`` kept (head dim ``d * width / heads``); the position
+    table is laid out for ``VIT_TABLE_IMAGE`` pixels (257 rows at patch
+    14).
+
+    Weights from ``split(key, 8 + 12 * layers)`` taken in order: patch
+    kernel and bias, class token, position table; per block ln1 scale and
+    bias, qkv, its bias, out, its bias, ln2 scale and bias, fc1, its bias,
+    fc2, its bias; the final norm's scale and bias; head, its bias.
+    Kernels are N(0, 1/fan_in); biases, LayerNorm biases, the class token
+    and the position table N(0, 0.02^2); LayerNorm scales 1 + N(0, 0.02^2).
+    """
+    d, d_ff = round(d * width), round(d_ff * width)
+    if d % heads:
+        raise ValueError(f"width {d} does not split into {heads} heads")
+    ks = iter(jax.random.split(key, 8 + 12 * layers))
+
+    def dense(k, n):
+        return {"w": _fan_in(next(ks), (k, n)), "b": _small(next(ks), (n,))}
+
+    def norm():
+        return {"scale": 1.0 + _small(next(ks), (d,)),
+                "bias": _small(next(ks), (d,))}
+    p = {"patch": {"w": _fan_in(next(ks), (patch, patch, 3, d)),
+                   "b": _small(next(ks), (d,))},
+         "cls": _small(next(ks), (1, 1, d)),
+         "pos": _small(next(ks), (1 + (VIT_TABLE_IMAGE // patch) ** 2, d)),
          "blocks": []}
     for _ in range(layers):
-        p["blocks"].append({
-            "qkv": jax.random.normal(next(ks), (d, 3 * d)) * 0.02,
-            "o": jax.random.normal(next(ks), (d, d)) * 0.02,
-            "w1": jax.random.normal(next(ks), (d, d_ff)) * 0.02,
-            "w2": jax.random.normal(next(ks), (d_ff, d)) * 0.02,
-            "ln1": jnp.zeros((d,)), "ln2": jnp.zeros((d,)),
-        })
+        p["blocks"].append({"ln1": norm(), "qkv": dense(d, 3 * d),
+                            "out": dense(d, d), "ln2": norm(),
+                            "fc1": dense(d, d_ff), "fc2": dense(d_ff, d)})
+    p["norm"] = norm()
+    p["head"] = dense(d, classes)
     p["meta"] = {"heads": heads, "patch": patch}
     return p
 
 
+def _layer_norm(x, p, eps: float = 1e-6):
+    m = jnp.mean(x, axis=-1, keepdims=True)
+    v = jnp.mean(jnp.square(x - m), axis=-1, keepdims=True)
+    return (x - m) * lax.rsqrt(v + eps) * p["scale"] + p["bias"]
+
+
+def _linear(x, p, use_kernel: bool, act: str = "none"):
+    """x (..., K) @ w + b, then ``act``: on the kernel path one padded
+    systolic call with the bias and activation in its epilogue."""
+    lead, k = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, k)
+    if use_kernel:
+        from repro.kernels import ops
+        y = ops.matmul_padded(x2, p["w"], p["b"], act=act)
+    else:
+        y = x2 @ p["w"] + p["b"]
+        if act == "gelu":
+            y = jax.nn.gelu(y, approximate=True)
+    return y.reshape(*lead, -1)
+
+
+def _resize_grid(pos, gh: int, gw: int):
+    """The position table for a ``gh`` x ``gw`` grid of patches: the class
+    row kept, the square grid of the rest resized linearly with its
+    corners aligned, as the release resizes a checkpoint's table for
+    another resolution (``scipy.ndimage.zoom``, order 1).  Index and
+    weight arithmetic only: no product."""
+    g = math.isqrt(pos.shape[0] - 1)
+    if (gh, gw) == (g, g):
+        return pos
+    grid = pos[1:].reshape(g, g, -1)
+    for axis, n in ((0, gh), (1, gw)):
+        src = np.linspace(0.0, g - 1, n)
+        lo = np.floor(src).astype(np.int32)
+        hi = np.minimum(lo + 1, g - 1)
+        t = jnp.asarray(src - lo, pos.dtype).reshape(
+            (-1, 1, 1) if axis == 0 else (1, -1, 1))
+        grid = (jnp.take(grid, lo, axis=axis) * (1 - t)
+                + jnp.take(grid, hi, axis=axis) * t)
+    return jnp.concatenate([pos[:1], grid.reshape(gh * gw, -1)], 0)
+
+
+def _self_attention(qkv, heads: int, use_kernel: bool):
+    """(B, S, 3d) fused projections, columns [q | k | v] each ``heads`` x
+    ``d/heads`` -> (B, S, d): non-causal softmax attention, scale
+    1/sqrt(d/heads), over the whole sequence as one block."""
+    B, S, d3 = qkv.shape
+    d = d3 // 3
+    q, k, v = qkv.reshape(B, S, 3, heads, d // heads).transpose(2, 0, 3, 1, 4)
+    if use_kernel:
+        from repro.kernels import ops
+        o = ops.attention(q, k, v, causal=False, bq=S, bk=S)
+    else:
+        from repro.kernels import ref
+        o = ref.attention_ref(q, k, v, causal=False)
+    return o.transpose(0, 2, 1, 3).reshape(B, S, d)
+
+
 def vit_apply(p, x, use_kernel: bool = False):
-    """x (B, H, W, 3) image."""
-    from repro.models.layers import rms_norm
-    patch = p["meta"]["patch"]
-    heads = p["meta"]["heads"]
-    B, H, W, C = x.shape
-    xp = x.reshape(B, H // patch, patch, W // patch, patch, C)
-    xp = xp.transpose(0, 1, 3, 2, 4, 5).reshape(B, -1, patch * patch * C)
-    h = xp @ p["patch"] + p["pos"][None, :xp.shape[1]]
-    h = jnp.concatenate([jnp.broadcast_to(p["cls"], (B, 1, h.shape[-1])), h], 1)
-    d = h.shape[-1]
-    hd = d // heads
-    for blk in p["blocks"]:
-        hn = rms_norm(h, blk["ln1"])
-        qkv = hn @ blk["qkv"]
-        q, k, v = jnp.split(qkv.reshape(B, -1, 3, heads, hd), 3, axis=2)
-        q, k, v = (t[:, :, 0].transpose(0, 2, 1, 3) for t in (q, k, v))
-        if use_kernel:
-            from repro.kernels import ops
-            o = ops.attention(q, k, v, causal=False,
-                              bq=min(128, q.shape[2]), bk=min(128, q.shape[2]))
-        else:
-            from repro.kernels import ref
-            o = ref.attention_ref(q, k, v, causal=False)
-        o = o.transpose(0, 2, 1, 3).reshape(B, -1, d)
-        h = h + o @ blk["o"]
-        hn = rms_norm(h, blk["ln2"])
-        h = h + jax.nn.gelu(hn @ blk["w1"]) @ blk["w2"]
-    return h[:, 0] @ p["head"]
+    """x (B, H, W, 3) image -> logits (B, classes), as the authors' release
+    (``google-research/vision_transformer``, ``models_vit.py``) computes
+    them: the patch embedding as a stride-``patch`` convolution with bias
+    (scope ``conv0``); the class token prepended and the position table
+    added (``tokens``); pre-norm blocks (``block<i>``: ``ln1``, ``qkv``,
+    ``attn``, ``out``, ``ln2``, ``fc1`` with tanh GELU, ``fc2``, residual
+    adds); the final LayerNorm of the class token (``norm``) and the head
+    (``head``).  The fused QKV projection is the release's three."""
+    patch, heads = p["meta"]["patch"], p["meta"]["heads"]
+    B, H, W, _ = x.shape
+    if H % patch or W % patch:
+        raise ValueError(f"a {H}x{W} image does not tile into "
+                         f"{patch}x{patch} patches")
+    h = _numbered(use_kernel)(x, p["patch"]["w"], patch, p["patch"]["b"])
+    with jax.named_scope("tokens"):
+        d = h.shape[-1]
+        cls = jnp.broadcast_to(p["cls"], (B, 1, d))
+        h = jnp.concatenate([cls, h.reshape(B, -1, d)], 1)
+        h = h + _resize_grid(p["pos"], H // patch, W // patch)
+    for i, blk in enumerate(p["blocks"]):
+        with jax.named_scope(f"block{i}"):
+            with jax.named_scope("ln1"):
+                y = _layer_norm(h, blk["ln1"])
+            with jax.named_scope("qkv"):
+                y = _linear(y, blk["qkv"], use_kernel)
+            with jax.named_scope("attn"):
+                y = _self_attention(y, heads, use_kernel)
+            with jax.named_scope("out"):
+                y = _linear(y, blk["out"], use_kernel)
+            h = h + y
+            with jax.named_scope("ln2"):
+                y = _layer_norm(h, blk["ln2"])
+            with jax.named_scope("fc1"):
+                y = _linear(y, blk["fc1"], use_kernel, act="gelu")
+            with jax.named_scope("fc2"):
+                y = _linear(y, blk["fc2"], use_kernel)
+            h = h + y
+    # the final norm is per token, so normalising the class token alone
+    # is the release's norm-then-take
+    with jax.named_scope("norm"):
+        y = _layer_norm(h[:, 0], p["norm"])
+    with jax.named_scope("head"):
+        return _linear(y, p["head"], use_kernel)

@@ -54,6 +54,22 @@ def test_flash_attention(b, h, kv, sq, skv, d, causal, window):
                                rtol=1e-3, atol=2e-4)
 
 
+@pytest.mark.parametrize("s", [5, 10, 257])
+@pytest.mark.parametrize("d", [8, 80])
+@pytest.mark.parametrize("block", [32, "whole"])
+def test_flash_attention_ragged_non_causal(s, d, block):
+    """A length that is not a multiple of the block (257 is prime), at
+    ViT-H/14's head dim 80: padded up to whole blocks with the padded keys
+    given no weight, or taken whole as one block."""
+    ks = jax.random.split(KEY, 3)
+    q, k, v = (jax.random.normal(kk, (1, 2, s, d), jnp.float32) for kk in ks)
+    b = s if block == "whole" else block
+    got = ops.attention(q, k, v, causal=False, bq=b, bk=b)
+    want = ref.attention_ref(q, k, v, causal=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-3, atol=2e-4)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_dtype(dtype):
     ks = jax.random.split(KEY, 3)

@@ -12,6 +12,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lindley import lindley_scan
 from repro.kernels.systolic_matmul import systolic_matmul
 from repro.kernels.vector_engine import fused_affine_act
@@ -56,6 +58,27 @@ def test_systolic_matmul_compiles_at_resnet50_shapes(one_chip, m, k, n,
     with jax.default_matmul_precision(precision):
         _compile(lambda x, w: systolic_matmul(x, w, interpret=False),
                  one_chip, (m, k), (k, n))
+
+
+def test_flash_attention_compiles_at_vit_h14_shapes(one_chip):
+    """One ViT-H/14 block's attention at 224 px: 16 heads of 80 over 257
+    tokens, the whole sequence as one block."""
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=False, bq=257,
+                                             bk=257, interpret=False),
+             one_chip, *[(1, 16, 257, 80)] * 3)
+
+
+@pytest.mark.parametrize("k,n,act", [(1280, 3840, "none"),     # qkv
+                                     (1280, 5120, "gelu"),     # fc1
+                                     (5120, 1280, "none")])    # fc2
+def test_padded_systolic_matmul_compiles_at_vit_h14_shapes(one_chip, k, n,
+                                                           act):
+    """A ViT-H/14 block GEMM over 257 tokens, padded to whole tiles, with
+    its bias and activation in the epilogue."""
+    with jax.default_matmul_precision("float32"):
+        _compile(lambda x, w, b: ops.matmul_padded(x, w, b, act=act,
+                                                   interpret=False),
+                 one_chip, (257, k), (k, n), (n,))
 
 
 def test_fused_affine_act_compiles_at_a_224_image(one_chip):
