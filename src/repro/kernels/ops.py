@@ -9,7 +9,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro.kernels.systolic_matmul import systolic_matmul
+from repro.kernels.systolic_matmul import systolic_matmul, tile_vmem_bytes
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.vector_engine import (fused_affine_act, quantize_int8,
                                          dequantize_int8)
@@ -30,17 +30,80 @@ def matmul(x, w, b=None, *, act="none", bm=128, bn=128, bk=128,
                            if interpret is None else interpret)
 
 
-def matmul_padded(x, w, b=None, *, act="none", bm=128, bn=128, bk=128,
+# The tile rule of ``matmul_tiles``: the VMEM its tiles may take
+# (``tile_vmem_bytes``), the most rows in a tile, and the grid steps a call
+# should keep, so that the next weight tile's DMA overlaps this tile's matmul
+TILE_VMEM_BUDGET = 24 << 20
+MAX_ROW_TILE = 1024
+MIN_GRID_STEPS = 4
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _row_tile(M: int) -> int:
+    """Rows per tile: all of M in one tile where it fits; else M split into
+    as few tiles as ``MAX_ROW_TILE`` allows, exactly where a split into
+    whole multiples of 8 of at least 128 rows exists, else evenly with
+    the last tile padded."""
+    if M <= MAX_ROW_TILE:
+        return M
+    t = -(-M // MAX_ROW_TILE)
+    if M % 8 == 0:
+        for n in range(t, M // 128 + 1):
+            if (M // 8) % n == 0:
+                return M // n
+    return _round_up(-(-M // t), 8)
+
+
+def matmul_tiles(M: int, K: int, N: int) -> tuple:
+    """``(bm, bk, bn)`` for an (M, K) @ (K, N) call of the systolic kernel,
+    from its shape alone.
+
+    ``bm`` covers M in as few row tiles as ``MAX_ROW_TILE`` allows
+    (:func:`_row_tile`).  ``bk`` is the whole of K where that fits the
+    VMEM budget beside a 128-wide weight tile, so the x tile stays put while
+    the weight tiles stream past it; else the largest multiple of 128 that
+    divides K padded to 128 and fits.  ``bn`` is the whole of N where N is
+    not a multiple of 128 and fits (no padded column); else the largest
+    multiple of 128 that divides N padded to 128, fits, and leaves
+    ``MIN_GRID_STEPS`` grid steps, or the smallest that fits where none
+    does.  A tile equal to its whole dimension needs no padding."""
+    bm = _row_tile(M)
+    n_rows = -(-M // bm)
+
+    def fits(bk, bn):
+        return tile_vmem_bytes(bm, bk, bn) <= TILE_VMEM_BUDGET
+
+    if fits(K, min(N, 128)):
+        bk = K
+    else:
+        Kp = _round_up(K, 128)
+        bk = max((d for d in range(128, Kp, 128)
+                  if Kp % d == 0 and fits(d, min(N, 128))), default=128)
+    nk = -(-K // bk)
+    if N % 128 and fits(bk, N):
+        return bm, bk, N
+    Np = _round_up(N, 128)
+    widths = [d for d in range(128, Np + 1, 128)
+              if Np % d == 0 and fits(bk, d)]
+    enough = [d for d in widths if n_rows * (Np // d) * nk >= MIN_GRID_STEPS]
+    return bm, bk, max(enough) if enough else min(widths, default=128)
+
+
+def matmul_padded(x, w, b=None, *, act="none", bm=None, bn=None, bk=None,
                   out_dtype=None, interpret=None):
-    """``matmul`` for arbitrary shapes: zero-pads (M, K, N) to tile
-    multiples — the DSA compiler's padding pass (§V)."""
+    """``matmul`` for arbitrary shapes — the DSA compiler's padding pass
+    (§V).  Tiles left out come from :func:`matmul_tiles`; each dimension is
+    zero-padded to a whole number of its tiles and the result cut back to
+    (M, N)."""
     import jax.numpy as jnp
     M, K = x.shape
     N = w.shape[1]
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    Mp = -(-M // bm) * bm
-    Kp = -(-K // bk) * bk
-    Np = -(-N // bn) * bn
+    tm, tk, tn = matmul_tiles(M, K, N)
+    bm, bk, bn = min(bm or tm, M), min(bk or tk, K), min(bn or tn, N)
+    Mp, Kp, Np = _round_up(M, bm), _round_up(K, bk), _round_up(N, bn)
     xp = jnp.pad(x, ((0, Mp - M), (0, Kp - K)))
     wp = jnp.pad(w, ((0, Kp - K), (0, Np - N)))
     bp = jnp.pad(b, (0, Np - N)) if b is not None else None

@@ -13,7 +13,11 @@ GEMM) is fused into the epilogue on the last K step, so GEMM outputs never
 round-trip to HBM — the paper's operator-fusion compiler pass.
 
 Grid: (M/bm, N/bn, K/bk), K innermost (sequential accumulation into an fp32
-VMEM scratch accumulator).
+VMEM scratch accumulator).  The caller picks the tiles: ``ops.matmul_tiles``
+chooses them from the GEMM's shape, and a tile as large as its dimension may
+take any size.  Where the tiles need more VMEM than the scoped default, the
+kernel raises its VMEM limit to twice what they take (:func:`tile_vmem_bytes`)
+and 4 MiB more.
 """
 from __future__ import annotations
 
@@ -34,6 +38,21 @@ _ACTS = {
     "tanh": jnp.tanh,
     "sigmoid": jax.nn.sigmoid,
 }
+
+
+# the Mosaic compiler's scoped VMEM limit on a TPU v5e unless a kernel asks
+# for more; the core has 128 MiB
+SCOPED_VMEM_BYTES = 16 << 20
+
+
+def tile_vmem_bytes(bm: int, bk: int, bn: int, itemsize: int = 4) -> int:
+    """VMEM the kernel's tiles take: x, w, the bias row and the output
+    double-buffered, and the float32 accumulator, each block padded to
+    whole (8, 128) tiles."""
+    def tile(rows, cols):
+        return -(-rows // 8) * 8 * -(-cols // 128) * 128
+    return (itemsize * (2 * tile(bm, bk) + 2 * tile(bk, bn) + 2 * tile(1, bn)
+                        + 2 * tile(bm, bn)) + 4 * tile(bm, bn))
 
 
 def _matmul_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, act: str,
@@ -68,7 +87,8 @@ def systolic_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
                     *, act: str = "none", bm: int = 128, bn: int = 128,
                     bk: int = 128, out_dtype=None,
                     interpret: bool = False) -> jax.Array:
-    """(M, K) @ (K, N) [+ b] with fused epilogue.  Dims must tile evenly."""
+    """(M, K) @ (K, N) [+ b] with fused epilogue.  Dims must tile evenly;
+    a tile equal to its whole dimension may have any size."""
     M, K = x.shape
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
@@ -89,6 +109,12 @@ def systolic_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
     kernel = functools.partial(
         _matmul_kernel if b is not None else _matmul_kernel_no_bias,
         act=act, nk=nk, out_dtype=out_dtype)
+    # Mosaic's float32 matmul splits its operands into bfloat16 parts
+    # beside the tiles, up to about 1.6x the x and w tiles again (least
+    # limits found by compiling for a described v5e): room for twice the
+    # tiles
+    tiles = tile_vmem_bytes(bm, bk, bn, jnp.dtype(x.dtype).itemsize)
+    vmem = 2 * tiles + (4 << 20)
 
     return pl.pallas_call(
         kernel,
@@ -98,7 +124,8 @@ def systolic_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem if vmem > SCOPED_VMEM_BYTES else None),
         interpret=interpret,
         name="systolic_matmul",
     )(*args)

@@ -40,6 +40,112 @@ def test_matmul_padded_arbitrary_shapes():
                                rtol=1e-4, atol=1e-3)
 
 
+# ViT-H/14's block GEMMs over 257 tokens (qkv, out, fc1, fc2), then
+# ResNet-50's stem and layer-4 3x3 convolution and YOLOv3's stem
+VIT_BLOCK_GEMMS = [(257, 1280, 3840), (257, 1280, 1280), (257, 1280, 5120),
+                   (257, 5120, 1280)]
+SERVED_GEMMS = VIT_BLOCK_GEMMS + [(12544, 147, 64), (49, 4608, 512),
+                                  (173056, 27, 32)]
+
+
+@pytest.mark.parametrize("m,k,n", SERVED_GEMMS)
+def test_matmul_tiles_are_legal_and_fit_the_budget(m, k, n):
+    from repro.kernels.systolic_matmul import tile_vmem_bytes
+    bm, bk, bn = ops.matmul_tiles(m, k, n)
+    assert bm % 8 == 0 or bm == m
+    assert bk % 128 == 0 or bk == k
+    assert bn % 128 == 0 or bn == n
+    assert bm <= ops.MAX_ROW_TILE
+    assert tile_vmem_bytes(bm, bk, bn) <= ops.TILE_VMEM_BUDGET
+    # padded at most to the next multiple of 128 (K, N) or of 8 a row tile
+    assert ops._round_up(k, bk) <= ops._round_up(k, 128)
+    assert ops._round_up(n, bn) <= ops._round_up(n, 128)
+    assert ops._round_up(m, bm) - m < 8 * -(-m // bm)
+
+
+@pytest.mark.parametrize("m,k,n", VIT_BLOCK_GEMMS)
+def test_matmul_tiles_cover_vit_tokens_in_one_row_tile(m, k, n):
+    """All 257 tokens in one row tile, so each weight tile is read once."""
+    bm, _, _ = ops.matmul_tiles(m, k, n)
+    assert bm == m
+
+
+def _pallas_grids(jaxpr):
+    """(kernel name, grid steps) of every ``pallas_call`` in ``jaxpr``."""
+    import math
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield (eqn.params["name"],
+                   math.prod(eqn.params["grid_mapping"].grid))
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_grids(sub)
+
+
+def test_vit_h14_request_takes_under_1000_systolic_grid_steps():
+    """The served ViT-H/14 program at published widths (224 px, 257
+    tokens; shapes only) runs its 130 GEMMs in under 1,000 grid steps of
+    the systolic kernel (115,300 with 128x128x128 tiles)."""
+    from repro.models import vision
+    kept = {}
+
+    def init():
+        leaves, treedef = jax.tree_util.tree_flatten(
+            vision.vit_init(jax.random.PRNGKey(0)))
+        kept.update(leaves=leaves, treedef=treedef)
+        return [v for v in leaves if isinstance(v, jax.Array)]
+
+    def apply(arrays, frame):
+        it = iter(arrays)
+        p = jax.tree_util.tree_unflatten(
+            kept["treedef"], [next(it) if isinstance(v, jax.Array) else v
+                              for v in kept["leaves"]])
+        return vision.vit_apply(p, frame, use_kernel=True)
+
+    jaxpr = jax.make_jaxpr(apply)(
+        jax.eval_shape(init), jax.ShapeDtypeStruct((1, 224, 224, 3),
+                                                   jnp.float32))
+    steps = [s for name, s in _pallas_grids(jaxpr.jaxpr)
+             if name == "systolic_matmul"]
+    assert len(steps) == 32 * 4 + 2
+    assert sum(steps) < 1000, sum(steps)
+
+
+@pytest.mark.parametrize("m,k,n,act,budget,rows,case", [
+    (257, 96, 640, "gelu", None, None, "ragged M whole, bn steps"),
+    (64, 2048, 256, "relu", 1 << 20, None, "K split"),
+    (200, 130, 300, "gelu", None, 64, "M padded, ragged K and N whole"),
+    (256, 40, 384, "none", None, 64, "M split exactly")])
+def test_matmul_padded_tiles_from_shape(monkeypatch, m, k, n, act, budget,
+                                        rows, case):
+    """``matmul_padded`` with the tiles of ``matmul_tiles`` against
+    ``x @ w + b``: a budget or a row cap shrunk so that small shapes take
+    each path of the rule."""
+    if budget:
+        monkeypatch.setattr(ops, "TILE_VMEM_BUDGET", budget)
+    if rows:
+        monkeypatch.setattr(ops, "MAX_ROW_TILE", rows)
+    bm, bk, bn = ops.matmul_tiles(m, k, n)
+    steps = -(-m // bm) * -(-k // bk) * -(-n // bn)
+    assert {"ragged M whole, bn steps": bm == m and bk == k and steps >= 4,
+            "K split": bk < k and bk % 128 == 0,
+            "M padded, ragged K and N whole": (m % bm and bk == k
+                                               and bn == n),
+            "M split exactly": bm < m and m % bm == 0}[case]
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    x = jax.random.normal(k1, (m, k), jnp.float32)
+    w = jax.random.normal(k2, (k, n), jnp.float32)
+    b = jax.random.normal(k3, (n,), jnp.float32)
+    with jax.default_matmul_precision("float32"):
+        got = ops.matmul_padded(x, w, b, act=act)
+        want = ref.matmul_ref(x, w, b, act=act)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-3)
+
+
 @pytest.mark.parametrize("b,h,kv,sq,skv,d", [
     (2, 8, 2, 128, 128, 64), (1, 4, 1, 64, 128, 32), (2, 4, 4, 128, 64, 64)])
 @pytest.mark.parametrize("causal,window", [(True, 0), (False, 0), (True, 48)])

@@ -73,8 +73,10 @@ def test_flash_attention_compiles_at_vit_h14_shapes(one_chip):
                                      (5120, 1280, "none")])    # fc2
 def test_padded_systolic_matmul_compiles_at_vit_h14_shapes(one_chip, k, n,
                                                            act):
-    """A ViT-H/14 block GEMM over 257 tokens, padded to whole tiles, with
-    its bias and activation in the epilogue."""
+    """A ViT-H/14 block GEMM over 257 tokens in the tiles that
+    ``ops.matmul_tiles`` picks (all 257 rows in one tile, whole K where it
+    fits, the VMEM limit raised where the tiles need more than the
+    default), with its bias and activation in the epilogue."""
     with jax.default_matmul_precision("float32"):
         _compile(lambda x, w, b: ops.matmul_padded(x, w, b, act=act,
                                                    interpret=False),
